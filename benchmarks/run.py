@@ -19,9 +19,11 @@ import json
 import sys
 
 from benchmarks import common
+from repro import compile_cache
 
 
 def main() -> None:
+    compile_cache.enable()
     from benchmarks import (
         bench_ccm,
         bench_edm,

@@ -1,7 +1,11 @@
 """All-pairs causal mapping, zebrafish-brain style (paper's headline use).
 
 Run:  PYTHONPATH=src python examples/ccm_brain.py [--series 24] [--steps 600]
-      PYTHONPATH=src python examples/ccm_brain.py --sharded --devices 8
+      PYTHONPATH=src python examples/ccm_brain.py --sharded
+
+``--sharded`` runs on every device JAX finds (an even count: the mesh is
+devices/2 × 2). On a CPU host, emulate them before starting, e.g.
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Builds a panel of coupled "neurons" where a few driver units force the
 rest, then runs the whole workload through ONE ``repro.edm.EDM`` session:
@@ -13,9 +17,15 @@ zero-collective shard_map engine instead, no other code change.
 """
 
 import argparse
-import os
 import sys
 import time
+
+import jax
+import numpy as np
+
+from repro import compile_cache
+from repro.data import timeseries as ts
+from repro.edm import EDM, EDMConfig
 
 
 def main():
@@ -24,17 +34,8 @@ def main():
     ap.add_argument("--steps", type=int, default=600)
     ap.add_argument("--drivers", type=int, default=2)
     ap.add_argument("--sharded", action="store_true")
-    ap.add_argument("--devices", type=int, default=8)
     args = ap.parse_args()
-
-    if args.sharded:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
-
-    import numpy as np
-
-    from repro.data import timeseries as ts
-    from repro.edm import EDM, EDMConfig
+    compile_cache.enable()
 
     panel_np, adj = ts.forced_network_panel(
         args.series, args.steps, n_drivers=args.drivers, coupling=0.1,
@@ -45,9 +46,13 @@ def main():
           f"{args.drivers} hidden drivers")
 
     cfg = EDMConfig(E_max=5)
+    n_dev = jax.device_count()
     if args.sharded:
         from repro.distributed import make_ccm_mesh
-        cfg = cfg.replace(mesh=make_ccm_mesh((args.devices // 2, 2),
+        if n_dev < 2 or n_dev % 2:
+            sys.exit(f"--sharded needs an even number of devices, "
+                     f"JAX found {n_dev}")
+        cfg = cfg.replace(mesh=make_ccm_mesh((n_dev // 2, 2),
                                              ("data", "model")))
     sess = EDM(panel_np, cfg)
 
@@ -62,7 +67,7 @@ def main():
     t0 = time.time()
     print(f"xmap plan: {sess.plan('xmap').describe()}")
     rho = sess.xmap(E_opt=E_opt)
-    where = (f"sharded, {args.devices} devices, E-grouped"
+    where = (f"sharded, {n_dev} devices, E-grouped"
              if args.sharded else "local, cached-kNN E-groups")
     print(f"CCM matrix ({where}): {time.time() - t0:.1f}s")
 

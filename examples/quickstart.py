@@ -14,11 +14,13 @@ the multi-E kNN state and every method reuses it.
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.data import timeseries as ts
 from repro.edm import EDM, EDMConfig
 
 
 def main():
+    compile_cache.enable()
     print("=" * 64)
     print("1. Simplex projection: forecasting deterministic chaos")
     x = jnp.asarray(ts.logistic_map(500))

@@ -30,11 +30,13 @@ import tempfile
 CHILD = """
 import os, signal, sys
 import numpy as np, jax.numpy as jnp
+from repro import compile_cache
 from repro.core import ccm
 from repro.data import timeseries as ts
 from repro.edm import EDM, EDMConfig
 
 mode, run_dir = sys.argv[1], sys.argv[2]
+compile_cache.enable()
 panel, _ = ts.forced_network_panel(8, 260, seed=21)
 cfg = EDMConfig(E=3, batch_libs=2)   # 4 tiles of 2 library rows
 
@@ -63,6 +65,9 @@ def _child(mode: str, run_dir: str) -> subprocess.CompletedProcess:
 
 
 def main() -> None:
+    # Only the children compute, one at a time: each owns the device. The
+    # parent imports the package for its constants and schema checks but
+    # never starts a JAX backend (importing ``repro`` starts none).
     from repro.edm import PREEMPTED_EXIT
 
     if len(sys.argv) > 1:

@@ -27,15 +27,17 @@ all workers alive, the registry must respect the byte budget, and at
 least one eviction must actually have happened (else the soak proved
 nothing).
 
-Part 3, the durability smoke (PR 10): a CHILD process runs a durable
-server (``state_dir=``) behind HTTP; the parent registers a panel and
-streams append ticks over the wire, then **kill -9**'s the child
-mid-stream. A restarted child recovers from the WAL and must serve
+Part 3, the durability smoke, runs FIRST: a CHILD process runs a
+durable server (``state_dir=``) behind HTTP; the parent registers a
+panel and streams append ticks over the wire, then **kill -9**'s the
+child mid-stream. A restarted child recovers from the WAL and must serve
 answers **bit-identical** to a cold session at the last acked version;
 one more append then lands on the recovered log, SIGTERM drains the
 child gracefully (exit 0), and a final in-process ``EDMServer.recover``
 proves the whole history — pre-kill appends + post-recovery append —
-replays to the same bits.
+replays to the same bits. A device belongs to one process: the parent
+starts no JAX backend until the last child has exited, so it only
+collects the served answers over HTTP and checks them afterwards.
 
 Run: ``PYTHONPATH=src python examples/serve_edm.py [out_dir]``
 
@@ -56,7 +58,7 @@ import urllib.request
 
 import numpy as np
 
-from repro import telemetry
+from repro import compile_cache, telemetry
 from repro.data import timeseries as ts
 from repro.edm import EDM, EDMConfig
 from repro.serving import EDMServer, serve_http
@@ -307,6 +309,7 @@ def child(state_dir: str) -> None:
     """The durable server process: recover-or-create, serve until
     terminated (SIGTERM → drain → exit 0; SIGKILL → the WAL's job)."""
     from repro.serving import run_until_terminated
+    compile_cache.enable()
     panels = os.path.join(state_dir, "panels")
     if os.path.isdir(panels) and os.listdir(panels):
         srv = EDMServer.recover(state_dir)
@@ -350,16 +353,12 @@ def durability_smoke() -> None:
         os.kill(p1.pid, signal.SIGKILL)  # mid-stream, no goodbye
         p1.wait(timeout=30)
 
-    # Restart: the child recovers from the WAL and serves the same bits
-    # a never-crashed session would at version 3.
+    # Restart: the child recovers from the WAL; its answers are checked
+    # below against a never-crashed session at version 3.
     p2, port = spawn()
     try:
-        o3 = oracle_at(3)
-        for pr in DUR_PAIRS:
-            r = _post(port, "ccm", panel="dur", lib=pr[0], target=pr[1],
-                      E=E_REQ)["result"]
-            _bit_match(r, o3.ccm_batch([pr], E=E_REQ)[0],
-                       f"post-kill9 ccm{pr}")
+        served3 = [_post(port, "ccm", panel="dur", lib=pr[0], target=pr[1],
+                         E=E_REQ)["result"] for pr in DUR_PAIRS]
         # the recovered WAL keeps accepting appends...
         info = _post(port, "append", panel="dur",
                      delta=deltas[3].tolist())["result"]
@@ -371,6 +370,10 @@ def durability_smoke() -> None:
         rc = p2.wait(timeout=60)
     assert rc == 0, f"graceful drain exited {rc}, want 0"
 
+    # No child is left: from here on the parent computes.
+    o3 = oracle_at(3)
+    for pr, r in zip(DUR_PAIRS, served3):
+        _bit_match(r, o3.ccm_batch([pr], E=E_REQ)[0], f"post-kill9 ccm{pr}")
     rec = EDMServer.recover(state_dir, autostart=False)
     try:
         assert rec.recovery_report["dur"]["version"] == 4, \
@@ -395,6 +398,7 @@ if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
         child(sys.argv[2])
     else:
+        compile_cache.enable()
+        durability_smoke()
         main()
         soak()
-        durability_smoke()

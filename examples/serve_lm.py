@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS, SKIP_CELLS, get_config
 from repro.models import transformer as tf
 from repro.serving import ServeEngine
@@ -25,6 +26,7 @@ def main():
                              if "decode_32k" not in SKIP_CELLS.get(a, set())])
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch, smoke=True)
     params = tf.init_params(cfg, jax.random.key(0))

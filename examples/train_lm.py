@@ -14,6 +14,7 @@ straggler flagging.
 import argparse
 import dataclasses
 
+from repro import compile_cache
 from repro.configs import TrainConfig, get_config
 from repro.data.pipeline import TokenPipeline
 from repro.training import train
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--microbatch", type=int, default=2)
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    compile_cache.enable()
 
     p = PRESETS[args.preset]
     cfg = dataclasses.replace(

@@ -1,49 +1,19 @@
-"""jax version-compatibility shims.
+"""Mesh and shard_map entry points on the installed JAX (0.9) API.
 
-The framework targets the current public APIs (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``); the container's
-jax 0.4.x still has shard_map under ``jax.experimental`` (with the older
-``check_rep`` spelling) and no mesh axis_types. Every mesh/shard_map call
-site goes through these two helpers so the whole repo degrades together.
+Every mesh / shard_map call site imports these names, so a move in
+JAX's public API is one edit here.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax ≥ 0.6-style public API
-    _new_shard_map = jax.shard_map
-    _old_shard_map = None
-except AttributeError:  # jax 0.4.x
-    _new_shard_map = None
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across jax versions (check_vma ↔ check_rep)."""
-    if _new_shard_map is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` across jax versions (psum(1) on 0.4.x)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions (axis_types only where it
-    exists — everything here uses Auto axes, the 0.4.x default)."""
-    try:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types (every mesh here is Auto)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -221,11 +221,7 @@ DEFAULT_BATCH_BUDGET_MB_CPU = 32
 
 
 def _default_budget_mb() -> int:
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:  # pragma: no cover - no backend at all
-        platform = "cpu"
-    return (DEFAULT_BATCH_BUDGET_MB_CPU if platform == "cpu"
+    return (DEFAULT_BATCH_BUDGET_MB_CPU if jax.devices()[0].platform == "cpu"
             else DEFAULT_BATCH_BUDGET_MB)
 
 

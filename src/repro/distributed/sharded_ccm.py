@@ -34,7 +34,11 @@ from repro.core.embedding import embed_offset, num_embedded, pred_rows
 from repro.kernels import ops
 
 from repro.compat import make_mesh as make_ccm_mesh  # noqa: F401 (re-export)
-from repro.compat import shard_map as _shard_map
+from repro.compat import shard_map
+
+# Every per-shard body here runs Pallas kernels, whose out_shapes carry no
+# varying-mesh-axes annotation, so shard_map's vma check cannot type them.
+_shard_map = functools.partial(shard_map, check_vma=False)
 
 
 def pad_to_multiple(x: jax.Array, multiple: int, axis: int = 0) -> jax.Array:

@@ -36,33 +36,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref as _ref
-from repro.kernels.ref import PAD_IDX, _INF
+from repro.kernels.ref import PAD_IDX
+from repro.kernels.topk import _BIG_I, merge_kbest
 
-_BIG_I = 2**30  # python int: jnp constants must not be captured by kernels
 
-
-def _select_kernel(cd_ref, ci_ref, dk_ref, ik_ref, *, k, br):
+def _select_kernel(cd_ref, ci_ref, dk_ref, ik_ref, *, k):
     """Per-row k smallest (value asc, index asc) of a candidate block.
 
     Inputs are positive squared distances (inf = masked or garbage) with
     per-row-unique indices (sentinels ≥ _BIG_I for garbage). Pure
     selection — the output value bits are copies of input bits.
     """
-    i0 = pl.program_id(0) * br
-    cand_d = cd_ref[pl.dslice(i0, br), :]
-    cand_i = ci_ref[pl.dslice(i0, br), :]
-    best_d, best_i = [], []
-    for _ in range(k):
-        m = jnp.min(cand_d, axis=1, keepdims=True)
-        sel = jnp.where(cand_d == m, cand_i, _BIG_I + 2**20)
-        bi = jnp.min(sel, axis=1, keepdims=True)  # stable ties: min index
-        best_d.append(m)
-        best_i.append(bi)
-        removed = cand_i == bi
-        cand_d = jnp.where(removed, jnp.inf, cand_d)
-        cand_i = jnp.where(removed, _BIG_I + 2**20, cand_i)
-    dk_ref[...] = jnp.concatenate(best_d, axis=1)
-    ik_ref[...] = jnp.concatenate(best_i, axis=1)
+    dk_ref[...], ik_ref[...] = merge_kbest(cd_ref[...], ci_ref[...], k,
+                                           big=_BIG_I + 2**20)
 
 
 def _select(cand_d, cand_i, *, k, block, interpret):
@@ -75,11 +61,11 @@ def _select(cand_d, cand_i, *, k, block, interpret):
     cand_d = jnp.pad(cand_d, ((0, pad), (0, 0)), constant_values=jnp.inf)
     cand_i = jnp.pad(cand_i, ((0, pad), (0, 0)), constant_values=_BIG_I)
     dk, ik = pl.pallas_call(
-        functools.partial(_select_kernel, k=k, br=br),
+        functools.partial(_select_kernel, k=k),
         grid=(g,),
         in_specs=[
-            pl.BlockSpec((g * br, C), lambda i: (0, 0)),
-            pl.BlockSpec((g * br, C), lambda i: (0, 0)),
+            pl.BlockSpec((br, C), lambda i: (i, 0)),
+            pl.BlockSpec((br, C), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, k), lambda i: (i, 0)),

@@ -19,11 +19,12 @@ retire-by-index so rows with < k valid candidates emit distinct fill
 entries, sqrt once after the last column step); see its docstring for
 the tie-order proof.
 
-VMEM per cell is O(L + br·bc + br·k): two layouts of the one series
-being processed (column/row copies, as in ``knn_multi_e.py``), the
-distance block, and the running k-best — per-cell footprint does not
-grow with B, which is what lets B scale to the host-side memory budget
-(``core.ccm.auto_batch_libs``) instead of a VMEM ceiling.
+VMEM per cell is O(E·(br + bc) + br·bc + br·k): the row and column
+tiles' lag-shifted slices of the one series being processed (the
+tile-aligned ``ref.lag_rows`` layout, as in ``knn_multi_e.py``), the
+distance block, and the running k-best — per-cell footprint grows with
+neither L nor B, which is what lets B scale to the host-side memory
+budget (``core.ccm.auto_batch_libs``) instead of a VMEM ceiling.
 """
 
 from __future__ import annotations
@@ -34,12 +35,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import num_embedded, strict_sq
+from repro.kernels.ref import lag_rows, num_embedded, strict_sq
+from repro.kernels.topk import _BIG_I, merge_kbest
 
-_BIG_I = 2**30  # python int: jnp constants must not be captured by kernels
 
-
-def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E, tau, k, mx, br, bc, gj,
+def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E, k, mx, br, bc, gj,
             exclude_self):
     i0 = pl.program_id(1) * br
     j = pl.program_id(2)
@@ -54,8 +54,8 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E, tau, k, mx, br, bc, gj,
     cols = j0 + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1)
     acc = jnp.zeros((br, bc), jnp.float32)
     for e in range(E):  # E ≤ ~20: unrolled, as in knn_multi_e.py
-        xi = xc_ref[pl.dslice(i0 + e * tau, br), :]  # (br, 1) sublanes
-        xj = xr_ref[:, pl.dslice(j0 + e * tau, bc)]  # (1, bc) lanes
+        xi = xc_ref[0, :, e:e + 1]  # (br, 1) sublanes: lag e of the row tile
+        xj = xr_ref[0, e:e + 1, :]  # (1, bc) lanes: lag e of the column tile
         d = xi - xj
         acc = acc + strict_sq(d)
     invalid = cols > mx  # static cap, pre-clamped to Lp − 1
@@ -64,20 +64,7 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E, tau, k, mx, br, bc, gj,
     cand_d = jnp.concatenate(
         [jnp.where(invalid, jnp.inf, acc), dk_ref[0]], axis=1)
     cand_i = jnp.concatenate([cols, ik_ref[0]], axis=1)
-    best_d, best_i = [], []
-    for _ in range(k):
-        m = jnp.min(cand_d, axis=1, keepdims=True)
-        sel = jnp.where(cand_d == m, cand_i, _BIG_I)
-        bi = jnp.min(sel, axis=1, keepdims=True)  # stable ties: min index
-        best_d.append(m)
-        best_i.append(bi)
-        # Retire the winner by index (clearing BOTH arrays) — inf-distance
-        # entries can't be retired via distance alone; see knn_multi_e.py.
-        removed = cand_i == bi
-        cand_d = jnp.where(removed, jnp.inf, cand_d)
-        cand_i = jnp.where(removed, _BIG_I, cand_i)
-    dk_ref[0] = jnp.concatenate(best_d, axis=1)
-    ik_ref[0] = jnp.concatenate(best_i, axis=1)
+    dk_ref[0], ik_ref[0] = merge_kbest(cand_d, cand_i, k)
 
     @pl.when(j == gj - 1)
     def _finalize():  # squared → Euclidean, once all columns are merged
@@ -95,16 +82,18 @@ def _call(X, *, E, tau, k, mx, exclude_self, block, interpret):
     bc = max(128, min(block[1], Lp))
     gi = pl.cdiv(Lp, br)
     gj = pl.cdiv(Lp, bc)
-    # Pad so no in-kernel dynamic slice ever clamps (row/col + lag reach).
+    # Lag-shifted copies padded to whole tiles (row/col + lag reach).
     need = max(gi * br, gj * bc) + (E - 1) * tau
     Xp = jnp.pad(X.astype(jnp.float32), ((0, 0), (0, need - L)))
+    xr = lag_rows(Xp, E=E, tau=tau, width=gj * bc)  # (B, E, cols)
+    xc = jnp.swapaxes(lag_rows(Xp, E=E, tau=tau, width=gi * br), 1, 2)
     return pl.pallas_call(
-        functools.partial(_kernel, E=E, tau=tau, k=k, mx=mx, br=br, bc=bc,
+        functools.partial(_kernel, E=E, k=k, mx=mx, br=br, bc=bc,
                           gj=gj, exclude_self=exclude_self),
         grid=(B, gi, gj),
         in_specs=[
-            pl.BlockSpec((need, 1), lambda b, i, j: (0, b)),  # column copy
-            pl.BlockSpec((1, need), lambda b, i, j: (b, 0)),  # row copy
+            pl.BlockSpec((1, br, E), lambda b, i, j: (b, i, 0)),  # row tile
+            pl.BlockSpec((1, E, bc), lambda b, i, j: (b, 0, j)),  # col tile
         ],
         out_specs=[
             pl.BlockSpec((1, br, k), lambda b, i, j: (b, i, 0)),
@@ -115,7 +104,7 @@ def _call(X, *, E, tau, k, mx, exclude_self, block, interpret):
             jax.ShapeDtypeStruct((B, Lp, k), jnp.int32),
         ],
         interpret=interpret,
-    )(Xp.T, Xp)
+    )(xc, xr)
 
 
 def all_knn_batch(

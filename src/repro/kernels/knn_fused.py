@@ -22,18 +22,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import strict_sq
+from repro.kernels.ref import lag_rows, strict_sq
 
 
-def _kernel(mx_ref, xc_ref, xr_ref, dk_ref, ik_ref, *, E, tau, k, br, Lp,
+def _kernel(mx_ref, xc_ref, xr_ref, dk_ref, ik_ref, *, E, k, br, Lp,
             exclude_self):
     i0 = pl.program_id(0) * br
     # ---- Alg. 1 (fused embedding) on a (br, Lp) row block, in VMEM
     acc = jnp.zeros((br, Lp), jnp.float32)
     for kk in range(E):  # E ≤ 20: unrolled
-        xi = xc_ref[pl.dslice(i0 + kk * tau, br), :]  # (br, 1)
-        xj = xr_ref[:, pl.dslice(kk * tau, Lp)]  # (1, Lp)
+        xi = xc_ref[:, kk:kk + 1]  # (br, 1): lag kk of the row tile
+        xj = xr_ref[kk:kk + 1, :]  # (1, Lp): lag kk of every column
         d = xi - xj
         acc = acc + strict_sq(d)
     # ---- Alg. 2 masking + k-pass extraction, still in VMEM
@@ -79,19 +80,19 @@ def all_knn_fused(
         raise ValueError(f"series too short: L={L}, E={E}, tau={tau}")
     br = max(8, min(block_rows, Lp))
     gi = pl.cdiv(Lp, br)
-    need = gi * br + (E - 1) * tau  # no dynamic-slice clamping (row axis)
+    need = gi * br + (E - 1) * tau  # lag copies cover whole row tiles
     x32 = x.astype(jnp.float32)
     x32 = x32 - jnp.mean(x32)
     xpad = jnp.pad(x32, (0, max(need, L) - L))
     mx = jnp.full((1, 1), Lp - 1 if max_idx is None else max_idx, jnp.int32)
     dk, ik = pl.pallas_call(
-        functools.partial(_kernel, E=E, tau=tau, k=k, br=br, Lp=Lp,
+        functools.partial(_kernel, E=E, k=k, br=br, Lp=Lp,
                           exclude_self=exclude_self),
         grid=(gi,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((xpad.shape[0], 1), lambda i: (0, 0)),  # column
-            pl.BlockSpec((1, xpad.shape[0]), lambda i: (0, 0)),  # row
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # dynamic candidate cap
+            pl.BlockSpec((br, E), lambda i: (i, 0)),  # row-tile lags
+            pl.BlockSpec((E, Lp), lambda i: (0, 0)),  # every column's lags
         ],
         out_specs=[
             pl.BlockSpec((br, k), lambda i: (i, 0)),
@@ -102,5 +103,6 @@ def all_knn_fused(
             jax.ShapeDtypeStruct((Lp, k), jnp.int32),
         ],
         interpret=interpret,
-    )(mx, xpad[:, None], xpad[None, :])
+    )(mx, lag_rows(xpad, E=E, tau=tau, width=gi * br).T,
+      lag_rows(xpad, E=E, tau=tau, width=Lp))
     return dk, ik

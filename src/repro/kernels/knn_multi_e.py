@@ -29,11 +29,12 @@ a stable full-row partial sort (``jax.lax.top_k`` on the masked row), for
 any column tiling. After the last column step the squared running bests
 are rooted (sqrt) in place.
 
-VMEM per cell is O(L + br·bc + E_max·br·k_max): the raw series is
-cached in VMEM (kEDM keeps it in team scratch the same way), the
-distance block is a fixed (br, bc) tile, and the quadratic (br, Lp)
-row block of ``knn_fused.py`` is gone — Lp is bounded by the linear
-series cache, not by a full-width distance row in VMEM.
+VMEM per cell is O(E_max·(br + bc) + br·bc + E_max·br·k_max),
+independent of L: each cell receives only its row tile's and column
+tile's lag-shifted slices of the series (``ref.lag_rows``, built in the
+wrapper so every in-kernel slice is tile-aligned, as Mosaic requires),
+the distance block is a fixed (br, bc) tile, and the quadratic (br, Lp)
+row block of ``knn_fused.py`` is gone.
 
 Per-level semantics match ``ref.all_knn_multi_e``: level e (E = e+1) has
 Lp_E = L − e·τ valid rows/cols, k_E neighbors (E+1 by default), a static
@@ -51,17 +52,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import (
+    lag_rows,
     multi_e_ks,
     multi_e_max_idx,
     num_embedded,
     pad_multi_e_tables,
     strict_sq,
 )
+from repro.kernels.topk import _BIG_I, merge_kbest
 
-_BIG_I = 2**30  # python int: jnp constants must not be captured by kernels
 
-
-def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E_max, tau, ks, mxs,
+def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E_max, ks, mxs,
             br, bc, gj, exclude_self):
     i0 = pl.program_id(0) * br
     j = pl.program_id(1)
@@ -77,8 +78,8 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E_max, tau, ks, mxs,
     cols = j0 + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1)
     acc = jnp.zeros((br, bc), jnp.float32)
     for e in range(E_max):  # E_max ≤ ~20: unrolled, as in pairwise_dist.py
-        xi = xc_ref[pl.dslice(i0 + e * tau, br), :]  # (br, 1) sublanes
-        xj = xr_ref[:, pl.dslice(j0 + e * tau, bc)]  # (1, bc) lanes
+        xi = xc_ref[:, e:e + 1]  # (br, 1) sublanes: lag e of the row tile
+        xj = xr_ref[e:e + 1, :]  # (1, bc) lanes: lag e of the column tile
         d = xi - xj
         acc = acc + strict_sq(d)
         # ---- level-E extraction: merge this block into the running k-best
@@ -88,29 +89,8 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E_max, tau, ks, mxs,
         cand_d = jnp.concatenate(
             [jnp.where(invalid, jnp.inf, acc), dk_ref[e]], axis=1)
         cand_i = jnp.concatenate([cols, ik_ref[e]], axis=1)
-        best_d, best_i = [], []
-        for _ in range(ks[e]):
-            m = jnp.min(cand_d, axis=1, keepdims=True)
-            sel = jnp.where(cand_d == m, cand_i, _BIG_I)
-            bi = jnp.min(sel, axis=1, keepdims=True)  # stable ties: min index
-            best_d.append(m)
-            best_i.append(bi)
-            # Retire the winner by index, clearing BOTH arrays: inf-distance
-            # entries can't be retired via distance alone (they're already
-            # inf), and an un-cleared index would win every later inf-tie —
-            # re-emitting the same index on rows with < k valid candidates.
-            # Global indices are unique across the tile ∪ running set, so
-            # exactly the selected entry is removed (bi == _BIG_I only
-            # retires interchangeable init padding).
-            removed = cand_i == bi
-            cand_d = jnp.where(removed, jnp.inf, cand_d)
-            cand_i = jnp.where(removed, _BIG_I, cand_i)
-        pad = k_max - ks[e]
-        if pad:
-            best_d.append(jnp.full((br, pad), jnp.inf, jnp.float32))
-            best_i.append(jnp.full((br, pad), _BIG_I, jnp.int32))
-        dk_ref[e] = jnp.concatenate(best_d, axis=1)
-        ik_ref[e] = jnp.concatenate(best_i, axis=1)
+        dk_ref[e], ik_ref[e] = merge_kbest(cand_d, cand_i, ks[e],
+                                           width=k_max)
 
     @pl.when(j == gj - 1)
     def _finalize():  # squared → Euclidean, once all columns are merged
@@ -128,16 +108,18 @@ def _call(x, *, E_max, tau, ks, mxs, exclude_self, block, interpret):
     bc = max(128, min(block[1], L))
     gi = pl.cdiv(L, br)
     gj = pl.cdiv(L, bc)
-    # Pad so no in-kernel dynamic slice ever clamps (row/col + lag reach).
+    # Lag-shifted copies padded to whole tiles (row/col + lag reach).
     need = max(gi * br, gj * bc) + (E_max - 1) * tau
     xpad = jnp.pad(x.astype(jnp.float32), (0, need - L))
+    xr = lag_rows(xpad, E=E_max, tau=tau, width=gj * bc)  # (E_max, cols)
+    xc = lag_rows(xpad, E=E_max, tau=tau, width=gi * br).T  # (rows, E_max)
     dk, ik = pl.pallas_call(
-        functools.partial(_kernel, E_max=E_max, tau=tau, ks=ks, mxs=mxs,
+        functools.partial(_kernel, E_max=E_max, ks=ks, mxs=mxs,
                           br=br, bc=bc, gj=gj, exclude_self=exclude_self),
         grid=(gi, gj),
         in_specs=[
-            pl.BlockSpec((need, 1), lambda i, j: (0, 0)),  # column copy
-            pl.BlockSpec((1, need), lambda i, j: (0, 0)),  # row copy
+            pl.BlockSpec((br, E_max), lambda i, j: (i, 0)),  # row-tile lags
+            pl.BlockSpec((E_max, bc), lambda i, j: (0, j)),  # col-tile lags
         ],
         out_specs=[
             pl.BlockSpec((E_max, br, k_max), lambda i, j: (0, i, 0)),
@@ -148,7 +130,7 @@ def _call(x, *, E_max, tau, ks, mxs, exclude_self, block, interpret):
             jax.ShapeDtypeStruct((E_max, L, k_max), jnp.int32),
         ],
         interpret=interpret,
-    )(xpad[:, None], xpad[None, :])
+    )(xc, xr)
     return pad_multi_e_tables(dk, ik, E_max=E_max, tau=tau, ks=ks)
 
 

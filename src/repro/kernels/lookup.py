@@ -25,27 +25,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _gather_tile(yT_ref, i_ref, w_ref, j0, *, k, bj, bn, offset):
-    """Compute one (bj, bn) tile of predictions into a VMEM value."""
+def _gather_rows(yT_ref, i_ref, w_ref, o_ref, *, k, bj):
+    """Store one (bj, bn) tile of predictions into ``o_ref``, row by row.
 
-    hi = yT_ref.shape[0] - 1
+    ``i_ref``/``w_ref`` are the tile's flattened (bj·kp,) SMEM index and
+    weight blocks (row stride kp = k rounded up to 8, see ``_tiles``):
+    indices already carry the lookup offset and are clamped into the
+    series, so every scalar read drives one dynamic sublane load of a
+    (1, bn) target row.
+    """
+    bn = o_ref.shape[1]
+    kp = _row_stride(k)
 
-    def body(j, acc):
+    def body(j, carry):
         row = jnp.zeros((1, bn), jnp.float32)
         for kk in range(k):  # unrolled: k is small and static
-            # clamp: padded rows of ragged j-tiles hold undefined indices
-            idx = jnp.clip(i_ref[j, kk] + offset, 0, hi)
-            row = row + w_ref[j, kk] * yT_ref[pl.dslice(idx, 1), :]
-        return jax.lax.dynamic_update_slice(acc, row, (j, 0))
+            idx = i_ref[j * kp + kk]
+            row = row + w_ref[j * kp + kk] * yT_ref[pl.ds(idx, 1), :]
+        o_ref[pl.ds(j, 1), :] = row
+        return carry
 
-    return jax.lax.fori_loop(0, bj, body, jnp.zeros((bj, bn), jnp.float32))
+    jax.lax.fori_loop(0, bj, body, 0)
 
 
-def _kernel_lookup(yT_ref, i_ref, w_ref, o_ref, *, k, bj, bn, offset):
-    o_ref[...] = _gather_tile(yT_ref, i_ref, w_ref, None, k=k, bj=bj, bn=bn,
-                              offset=offset)
+def _row_stride(k):
+    """SMEM row stride of the tables: 1-D SMEM blocks must be a multiple
+    of 1024 words, so k is padded to a multiple of 8 (bj = 128 rows)."""
+    return -(-k // 8) * 8
+
+
+def _tiles(Y, idx, w, *, offset, block):
+    """Shared wrapper layout of both kernels.
+
+    Returns the tile sizes, the grid, the (L, N) transposed targets, and
+    the (gj·bj·kp,) flattened SMEM tables: the index table clamped into
+    the series (padded or masked slots may hold any index) and shifted
+    by ``offset``, both zero-padded to whole row tiles and to the row
+    stride kp (a padded slot gathers row 0 with weight 0).
+    """
+    N, L = Y.shape
+    Lp, k = idx.shape
+    bj = max(8, min(block[0], Lp))
+    bn = N if N <= block[1] else block[1]
+    gj, gn = pl.cdiv(Lp, bj), pl.cdiv(N, bn)
+    pad = ((0, gj * bj - Lp), (0, _row_stride(k) - k))
+    it = jnp.pad(jnp.clip(idx.astype(jnp.int32), 0, max(L - 1 - offset, 0))
+                 + offset, pad)
+    wt = jnp.pad(w.astype(jnp.float32), pad)
+    yT = Y.astype(jnp.float32).T
+    return bj, bn, gj, gn, yT, it.reshape(-1), wt.reshape(-1)
+
+
+def _target_specs(L, bn, bj, k):
+    """BlockSpecs of (yT, idx, w): the whole target history of one lane
+    block stays resident across the j sweep (single-buffered: its block
+    index changes only with n), the tables stream per row tile in SMEM."""
+    return [
+        pl.BlockSpec((L, bn), lambda n, j: (0, n),
+                     pipeline_mode=pl.Buffered(1)),
+        pl.BlockSpec((bj * _row_stride(k),), lambda n, j: (j,),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((bj * _row_stride(k),), lambda n, j: (j,),
+                     memory_space=pltpu.SMEM),
+    ]
+
+
+def _vmem_params(L, bn, bj):
+    """Scoped-VMEM limit that fits the resident (L, bn) target block.
+
+    At kEDM's longest published series (L = 29484) that block alone is
+    ≈15 MB, at the default scoped limit; the limit is raised to the
+    block plus room for the streamed tiles instead of capping L.
+    """
+    lanes = -(-bn // 128) * 128
+    need = 4 * lanes * (L + 8 * bj) + (4 << 20)
+    return pltpu.CompilerParams(vmem_limit_bytes=max(need, 32 << 20))
 
 
 @functools.partial(
@@ -63,36 +120,24 @@ def lookup(
     """Batched lookup via Pallas. Returns (N, Lp) float32."""
     N, L = Y.shape
     Lp, k = idx.shape
-    bj, bn = (max(8, min(block[0], Lp)), max(8, min(block[1], N)))
-    gj, gn = pl.cdiv(Lp, bj), pl.cdiv(N, bn)
-    # Pad the time axis so idx+offset slices never clamp, incl. the padded
-    # rows of ragged j-tiles (their idx payload is undefined → clamp-safe 0).
-    Lpad = L + 1
-    yT = jnp.pad(Y.astype(jnp.float32).T, ((0, Lpad - L), (0, 0)))
+    bj, bn, gj, gn, yT, it, wt = _tiles(Y, idx, w, offset=offset,
+                                        block=block)
     out = pl.pallas_call(
-        functools.partial(_kernel_lookup, k=k, bj=bj, bn=bn, offset=offset),
+        functools.partial(_gather_rows, k=k, bj=bj),
         grid=(gn, gj),
-        in_specs=[
-            pl.BlockSpec((Lpad, bn), lambda n, j: (0, n)),
-            pl.BlockSpec((bj, k), lambda n, j: (j, 0)),
-            pl.BlockSpec((bj, k), lambda n, j: (j, 0)),
-        ],
+        in_specs=_target_specs(L, bn, bj, k),
         out_specs=pl.BlockSpec((bj, bn), lambda n, j: (j, n)),
-        out_shape=jax.ShapeDtypeStruct((Lp, N), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((gj * bj, N), jnp.float32),
+        compiler_params=_vmem_params(L, bn, bj),
         interpret=interpret,
-    )(yT, _sanitize_idx(idx, L - 1 - offset), w.astype(jnp.float32))
-    return out.T
-
-
-def _sanitize_idx(idx: jax.Array, hi: int) -> jax.Array:
-    """Clamp indices into [0, hi]; padded tile rows may hold garbage."""
-    return jnp.clip(idx.astype(jnp.int32), 0, max(hi, 0))
+    )(yT, it, wt)
+    return out[:Lp].T
 
 
 # ---------------------------------------------------------------- fused rho
 
 
-def _kernel_rho(yT_ref, i_ref, w_ref, s_ref, *, k, bj, bn, offset, Lp):
+def _kernel_rho(yT_ref, i_ref, w_ref, yt_ref, s_ref, yh_ref, *, k, bj, Lp):
     j = pl.program_id(1)
     j0 = j * bj
 
@@ -100,9 +145,9 @@ def _kernel_rho(yT_ref, i_ref, w_ref, s_ref, *, k, bj, bn, offset, Lp):
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    yhat = _gather_tile(yT_ref, i_ref, w_ref, j0, k=k, bj=bj, bn=bn,
-                        offset=offset)
-    ytrue = yT_ref[pl.dslice(j0 + offset, bj), :]  # contiguous truth rows
+    _gather_rows(yT_ref, i_ref, w_ref, yh_ref, k=k, bj=bj)
+    yhat = yh_ref[...]
+    ytrue = yt_ref[...]  # the tile's truth rows, pre-shifted by offset
     # Mask ragged-edge rows with selects, not multiplies: the interpreter
     # (and Mosaic) pad ragged input blocks with undefined values, which may
     # be NaN — and NaN * 0 == NaN would poison the reduction.
@@ -157,22 +202,23 @@ def lookup_rho(
     """
     N, L = Y.shape
     Lp, k = idx.shape
-    bj, bn = (max(8, min(block[0], Lp)), max(8, min(block[1], N)))
-    gj, gn = pl.cdiv(Lp, bj), pl.cdiv(N, bn)
-    Lpad = L + bj + 1  # truth-row slice of the last ragged tile must not clamp
-    yT = jnp.pad(Y.astype(jnp.float32).T, ((0, Lpad - L), (0, 0)))
+    bj, bn, gj, gn, yT, it, wt = _tiles(Y, idx, w, offset=offset,
+                                        block=block)
+    # Aligned truth rows: row j of the tile is Y[:, j0 + j + offset].
+    ytrue = jnp.pad(jax.lax.slice_in_dim(yT, offset, offset + Lp, axis=0),
+                    ((0, gj * bj - Lp), (0, 0)))
     stats = pl.pallas_call(
-        functools.partial(_kernel_rho, k=k, bj=bj, bn=bn, offset=offset, Lp=Lp),
+        functools.partial(_kernel_rho, k=k, bj=bj, Lp=Lp),
         grid=(gn, gj),  # j innermost: stats block revisited across j
-        in_specs=[
-            pl.BlockSpec((Lpad, bn), lambda n, j: (0, n)),
-            pl.BlockSpec((bj, k), lambda n, j: (j, 0)),
-            pl.BlockSpec((bj, k), lambda n, j: (j, 0)),
+        in_specs=_target_specs(L, bn, bj, k) + [
+            pl.BlockSpec((bj, bn), lambda n, j: (j, n)),
         ],
         out_specs=pl.BlockSpec((8, bn), lambda n, j: (0, n)),
         out_shape=jax.ShapeDtypeStruct((8, N), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bj, bn), jnp.float32)],
+        compiler_params=_vmem_params(L, bn, bj),
         interpret=interpret,
-    )(yT, _sanitize_idx(idx, L - 1 - offset), w.astype(jnp.float32))
+    )(yT, it, wt, ytrue)
     M2a, M2b, C = stats[3], stats[4], stats[5]
     denom = jnp.sqrt(M2a * M2b)
     return jnp.where(denom > 0, C / jnp.maximum(denom, 1e-30), 0.0)

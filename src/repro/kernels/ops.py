@@ -44,11 +44,9 @@ _impl_stack: list[str] = []  # innermost use_impl override wins
 
 @functools.cache
 def _platform_default() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:  # pragma: no cover - no backend at all
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "ref"
+    # No fallback: a backend that fails to start raises here, instead of
+    # silently resolving the CPU oracle on a machine meant for the chip.
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def default_impl() -> str:

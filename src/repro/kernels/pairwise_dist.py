@@ -17,9 +17,10 @@ Two variants (DESIGN.md §2):
   (the fusion is preserved). ops.py centers the series first so the
   ‖z_i‖² + ‖z_j‖² − 2⟨z_i,z_j⟩ expansion is numerically safe.
 
-Layout trick: the series is passed twice, as a (Lpad, 1) column and a
-(1, Lpad) row, so the i-axis slices land on sublanes and the j-axis slices
-on lanes with no in-kernel transposes.
+Layout trick: the series is passed twice as lag-shifted copies
+(``ref.lag_rows``), a (rows, E) block per row tile and an (E, cols) block
+per column tile, so the i-axis lags land on sublanes and the j-axis lags
+on lanes with no in-kernel transposes and no unaligned slices.
 """
 
 from __future__ import annotations
@@ -30,36 +31,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import strict_sq
+from repro.kernels.ref import lag_rows, strict_sq
 
 MXU_K = 128  # MXU contraction width the embedding dim is padded to.
 
 
-def _kernel_vpu(xc_ref, xr_ref, o_ref, *, E: int, tau: int, bi: int, bj: int):
-    i0 = pl.program_id(0) * bi
-    j0 = pl.program_id(1) * bj
+def _kernel_vpu(xc_ref, xr_ref, o_ref, *, E: int, bi: int, bj: int):
     acc = jnp.zeros((bi, bj), jnp.float32)
     for k in range(E):  # E <= 20: unrolled, as in the paper's inner loop
-        xi = xc_ref[pl.dslice(i0 + k * tau, bi), :]  # (bi, 1) sublanes
-        xj = xr_ref[:, pl.dslice(j0 + k * tau, bj)]  # (1, bj) lanes
+        xi = xc_ref[:, k:k + 1]  # (bi, 1) sublanes: lag k of the row tile
+        xj = xr_ref[k:k + 1, :]  # (1, bj) lanes: lag k of the column tile
         d = xi - xj
         acc = acc + strict_sq(d)
     o_ref[...] = acc
 
 
-def _kernel_mxu(xc_ref, xr_ref, o_ref, *, E: int, tau: int, bi: int, bj: int):
-    i0 = pl.program_id(0) * bi
-    j0 = pl.program_id(1) * bj
-    # Build embedding tiles in-kernel (fusion preserved), padded to MXU width.
+def _kernel_mxu(xc_ref, xr_ref, o_ref, *, E: int, bi: int, bj: int):
+    # Embedding tiles straight from the lag blocks (fusion preserved),
+    # zero-padded to the MXU contraction width.
     zi = jnp.concatenate(
-        [xc_ref[pl.dslice(i0 + k * tau, bi), :] for k in range(E)]
-        + [jnp.zeros((bi, MXU_K - E), jnp.float32)],
-        axis=1,
+        [xc_ref[...], jnp.zeros((bi, MXU_K - E), jnp.float32)], axis=1,
     )  # (bi, 128)
     zjT = jnp.concatenate(
-        [xr_ref[:, pl.dslice(j0 + k * tau, bj)] for k in range(E)]
-        + [jnp.zeros((MXU_K - E, bj), jnp.float32)],
-        axis=0,
+        [xr_ref[...], jnp.zeros((MXU_K - E, bj), jnp.float32)], axis=0,
     )  # (128, bj)
     cross = jax.lax.dot_general(
         zi, zjT, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -92,8 +86,8 @@ def pairwise_distances(
     bj = max(8, bj)
     gi = pl.cdiv(Lp, bi)
     gj = pl.cdiv(Lp, bj)
-    # Pad so no in-kernel dynamic slice ever clamps (DESIGN.md §2): the last
-    # tile reads up to (tiles*b - b) + (E-1)tau + b.
+    # Pad so the lag copies cover whole tiles: the last tile reads up to
+    # (tiles*b - b) + (E-1)tau + b.
     need = max(gi * bi, gj * bj) + (E - 1) * tau
     x32 = x.astype(jnp.float32)
     # Centering makes the MXU norm-expansion numerically safe and is free
@@ -102,13 +96,14 @@ def pairwise_distances(
     xpad = jnp.pad(x32, (0, need - L))
     kern = _kernel_mxu if variant == "mxu" else _kernel_vpu
     return pl.pallas_call(
-        functools.partial(kern, E=E, tau=tau, bi=bi, bj=bj),
+        functools.partial(kern, E=E, bi=bi, bj=bj),
         grid=(gi, gj),
         in_specs=[
-            pl.BlockSpec((need, 1), lambda i, j: (0, 0)),  # column copy
-            pl.BlockSpec((1, need), lambda i, j: (0, 0)),  # row copy
+            pl.BlockSpec((bi, E), lambda i, j: (i, 0)),  # row-tile lags
+            pl.BlockSpec((E, bj), lambda i, j: (0, j)),  # column-tile lags
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Lp, Lp), jnp.float32),
         interpret=interpret,
-    )(xpad[:, None], xpad[None, :])
+    )(lag_rows(xpad, E=E, tau=tau, width=gi * bi).T,
+      lag_rows(xpad, E=E, tau=tau, width=gj * bj))

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_INF = jnp.float32(jnp.inf)
+_INF = np.float32(np.inf)  # numpy, not jnp: importing must not start a backend
 
 
 def strict_sq(d: jax.Array) -> jax.Array:
@@ -70,6 +70,21 @@ def delay_embed(x: jax.Array, E: int, tau: int) -> jax.Array:
     Lp = num_embedded(L, E, tau)
     cols = [jax.lax.dynamic_slice_in_dim(x, k * tau, Lp, axis=-1) for k in range(E)]
     return jnp.stack(cols, axis=-1)
+
+
+def lag_rows(x: jax.Array, *, E: int, tau: int, width: int) -> jax.Array:
+    """Lag-shifted copies ``out[…, e, j] = x[…, j + e·τ]`` → (…, E, width).
+
+    The layout the Pallas kernels read lag terms from. A tile starting at
+    column j0 finds its e-th lag at ``[e, j0:j0+b]``, so every in-kernel
+    slice starts on a tile boundary: Mosaic refuses the unaligned lane
+    offset ``j0 + e·τ`` that slicing the raw series would need. ``x``
+    must be at least ``width + (E−1)·τ`` long. Kernels that read lags
+    on sublanes (row tiles) take the (…, width, E) transpose.
+    """
+    return jnp.stack([jax.lax.slice_in_dim(x, e * tau, e * tau + width,
+                                           axis=-1) for e in range(E)],
+                     axis=-2)
 
 
 @functools.partial(jax.jit, static_argnames=("E", "tau"))
@@ -588,8 +603,15 @@ def smap_gram(
     return jnp.stack(Gs, axis=1), jnp.stack(Ms, axis=1)
 
 
+@jax.jit
 def pearson_rows(a: jax.Array, b: jax.Array) -> jax.Array:
-    """Row-wise Pearson correlation, two-pass (numerically stable)."""
+    """Row-wise Pearson correlation, two-pass (numerically stable).
+
+    Jitted so that a standalone call (``core.simplex_skill``) compiles
+    the same fused reductions as a call inlined into a larger program
+    (``lookup_rho``): dispatched op by op, XLA's CPU reductions round
+    the sums differently, ≈1 ULP of ρ.
+    """
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     am = a - jnp.mean(a, axis=-1, keepdims=True)

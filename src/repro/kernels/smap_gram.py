@@ -10,22 +10,21 @@ matrix. This kernel replaces that with the normal-equations accumulation
     M[j, θ, n] = Aᵀ W_{j,θ} y_n  (E+1,)
 
 streamed over library (column) tiles in the same design language as
-``knn_multi_e.py``: the raw series lives in VMEM (the delay embedding and
-the distances are fused in-kernel, never touching HBM), the grid is
-(row blocks, phase, column blocks) with the column axis minor/sequential,
-and the output blocks double as running accumulators revisited across all
-column steps. VMEM per cell is O(L + br·bc + T·(E+1)²·br + T·N·(E+1)·br)
-— no (rows, rows) weight or distance matrix ever exists anywhere.
+``knn_multi_e.py``: each cell reads its tiles' lag-shifted slices of the
+series (``ref.lag_rows``; the distances are fused in-kernel, never
+touching HBM), the grid is (row blocks, phase, column blocks) with the
+column axis minor/sequential, and the output blocks double as running
+accumulators revisited across all column steps. VMEM per cell is
+O(E·(br + bc) + br·bc + T·(E+1)²·br + T·N·(E+1)·br), independent of L —
+no (rows, rows) weight or distance matrix ever exists anywhere.
 
 The S-Map weight w_ij = exp(−θ d_ij / d̄_j) needs the full-row mean d̄_j
 *before* any weight can be formed, which a single streaming pass cannot
 provide. The middle grid axis is a two-phase sweep over the same column
 tiles: phase 0 recomputes each (br, bc) distance block and accumulates the
 row sums (→ d̄, an output block revisited across tiles), phase 1 recomputes
-the block again (O(E·br·bc), cheaper than round-tripping it through HBM;
-measured against a VMEM d-row cache and kept — see the ``cache_phase1``
-note on ``smap_gram``)
-and accumulates, per θ, the E+1 rank-(E+1) MXU matmuls (w ⊙ aᵖ) @ A_tile
+the block again (O(E·br·bc) VPU work that overlaps the phase-1 MXU
+matmuls, and cheaper than round-tripping it through HBM) and accumulates, per θ, the E+1 rank-(E+1) MXU matmuls (w ⊙ aᵖ) @ A_tile
 into the Gram/moment outputs. Degenerate rows (d̄ ≈ 0, constant series)
 take ratio 0 ⇒ weight 1 — see ``ref.smap_ratio``.
 
@@ -41,20 +40,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import _DBAR_TINY, num_embedded
+from repro.kernels.ref import _DBAR_TINY, lag_rows, num_embedded
 
 
-def _kernel(xc_ref, xr_ref, y_ref, ds_ref, g_ref, m_ref, *scratch, E, tau,
-            off, rows, thetas, br, bc, exclude_self):
+def _kernel(xc_ref, xr_ref, a_ref, y_ref, ds_ref, g_ref, m_ref, *, E,
+            rows, thetas, br, bc, exclude_self):
     i0 = pl.program_id(0) * br
     p = pl.program_id(1)  # 0: accumulate row sums (d̄) · 1: accumulate G, M
     j = pl.program_id(2)
     j0 = j * bc
     E1 = E + 1
     N = y_ref.shape[0]
-    dc_ref = scratch[0] if scratch else None  # cache_phase1 distance rows
 
     T = len(thetas)
 
@@ -71,30 +68,30 @@ def _kernel(xc_ref, xr_ref, y_ref, ds_ref, g_ref, m_ref, *scratch, E, tau,
     def compute_d():  # fused-embedding distance block, O(E·br·bc) VPU work
         acc = jnp.zeros((br, bc), jnp.float32)
         for e in range(E):  # E ≤ ~20: unrolled, as in pairwise_dist.py
-            xi = xc_ref[pl.dslice(i0 + e * tau, br), :]  # (br, 1) sublanes
-            xj = xr_ref[:, pl.dslice(j0 + e * tau, bc)]  # (1, bc) lanes
+            xi = xc_ref[:, e:e + 1]  # (br, 1) sublanes: lag e, row tile
+            xj = xr_ref[e:e + 1, :]  # (1, bc) lanes: lag e, column tile
             d = xi - xj
             acc = acc + d * d
         return jnp.sqrt(jnp.maximum(acc, 0.0))
 
-    def rowsum(d):  # d̄ numerator; self's zero distance is included
-        ds_ref[...] += jnp.sum(jnp.where(valid, d, 0.0), axis=1,
+    @pl.when(p == 0)
+    def _rowsum():  # d̄ numerator; self's zero distance is included
+        ds_ref[...] += jnp.sum(jnp.where(valid, compute_d(), 0.0), axis=1,
                                keepdims=True)
 
-    def _gram_accumulate(d):
+    @pl.when(p == 1)
+    def _gram():
+        d = compute_d()  # recomputed: cheaper than a round trip via HBM
         dbar = ds_ref[...] * (1.0 / rows)  # (br, 1)
         ratio = d / jnp.where(dbar > _DBAR_TINY, dbar, 1.0)
         invalid = ~valid
         if exclude_self:
             invalid = invalid | (cols_i == rows_i)  # leave-one-out
-        # Design-matrix tile in both layouts, straight from the series
-        # caches (no in-kernel transposes): A_i = [1, x_i, …, x_{i+(E−1)τ}].
-        at = jnp.concatenate(
-            [jnp.ones((bc, 1), jnp.float32)]
-            + [xc_ref[pl.dslice(j0 + e * tau, bc), :] for e in range(E)],
-            axis=1)  # (bc, E1)
+        # Design-matrix tile in both layouts, no in-kernel transposes:
+        # A_j = [1, x_j, …, x_{j+(E−1)τ}] as (bc, E1) rows and E1 lane rows.
+        at = a_ref[...]  # (bc, E1)
         arows = [jnp.ones((1, bc), jnp.float32)] + [
-            xr_ref[:, pl.dslice(j0 + e * tau, bc)] for e in range(E)]
+            xr_ref[e:e + 1, :] for e in range(E)]
         for t, theta in enumerate(thetas):  # |θ| ≤ ~16: unrolled
             w = jnp.where(invalid, 0.0,
                           jnp.exp(jnp.float32(-theta) * ratio))  # (br, bc)
@@ -103,39 +100,17 @@ def _kernel(xc_ref, xr_ref, y_ref, ds_ref, g_ref, m_ref, *scratch, E, tau,
                     w * arows[q], at, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
             for n in range(N):
-                yn = y_ref[pl.dslice(n, 1), pl.dslice(j0 + off, bc)]
+                yn = y_ref[n:n + 1, :]  # (1, bc): Tp-ahead truth, target n
                 m_ref[t, n] += jax.lax.dot_general(
                     w * yn, at, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-
-    if dc_ref is None:  # default: recompute the block in phase 1
-
-        @pl.when(p == 0)
-        def _rowsum():
-            rowsum(compute_d())
-
-        @pl.when(p == 1)
-        def _gram():
-            _gram_accumulate(compute_d())
-    else:  # cache_phase1: phase 0 spills the d rows to VMEM scratch
-
-        @pl.when(p == 0)
-        def _rowsum_cache():
-            d = compute_d()
-            rowsum(d)
-            dc_ref[:, pl.dslice(j0, bc)] = d
-
-        @pl.when(p == 1)
-        def _gram_cache():
-            _gram_accumulate(dc_ref[:, pl.dslice(j0, bc)])
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("E", "tau", "Tp", "thetas", "exclude_self", "block",
-                     "interpret", "cache_phase1"))
-def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret,
-          cache_phase1=False):
+                     "interpret"))
+def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret):
     L = x.shape[-1]
     rows = num_embedded(L, E, tau) - max(Tp, 0)
     off = (E - 1) * tau + Tp
@@ -146,22 +121,25 @@ def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret,
     bc = max(128, min(block[1], rows))
     gi = pl.cdiv(rows, br)
     gj = pl.cdiv(rows, bc)
-    # Pad so no in-kernel dynamic slice ever clamps (row/col + lag/Tp reach).
+    # Tile-aligned lag-shifted copies (``ref.lag_rows``), padded so the
+    # row/col + lag/Tp reach of the last tiles stays in bounds.
     need = max(gi * br, gj * bc) + (E - 1) * tau + max(Tp, 0)
     xpad = jnp.pad(x.astype(jnp.float32), (0, need - L))
     ypad = jnp.pad(Y.astype(jnp.float32), ((0, 0), (0, need - L)))
+    xc = lag_rows(xpad, E=E, tau=tau, width=gi * br).T  # (rows, E)
+    xr = lag_rows(xpad, E=E, tau=tau, width=gj * bc)  # (E, cols)
+    A = jnp.concatenate(  # (cols, E1) design matrix [1 | lags]
+        [jnp.ones((gj * bc, 1), jnp.float32), xr.T], axis=1)
+    yoff = jax.lax.slice_in_dim(ypad, off, off + gj * bc, axis=1)
     _, G, M = pl.pallas_call(
-        functools.partial(_kernel, E=E, tau=tau, off=off, rows=rows,
-                          thetas=thetas, br=br, bc=bc,
-                          exclude_self=exclude_self),
-        scratch_shapes=(
-            [pltpu.VMEM((br, gj * bc), jnp.float32)] if cache_phase1
-            else []),
+        functools.partial(_kernel, E=E, rows=rows, thetas=thetas, br=br,
+                          bc=bc, exclude_self=exclude_self),
         grid=(gi, 2, gj),
         in_specs=[
-            pl.BlockSpec((need, 1), lambda i, p, j: (0, 0)),  # column copy
-            pl.BlockSpec((1, need), lambda i, p, j: (0, 0)),  # row copy
-            pl.BlockSpec((N, need), lambda i, p, j: (0, 0)),  # target panel
+            pl.BlockSpec((br, E), lambda i, p, j: (i, 0)),  # row-tile lags
+            pl.BlockSpec((E, bc), lambda i, p, j: (0, j)),  # col-tile lags
+            pl.BlockSpec((bc, E1), lambda i, p, j: (j, 0)),  # design tile
+            pl.BlockSpec((N, bc), lambda i, p, j: (0, j)),  # target truth
         ],
         out_specs=[
             pl.BlockSpec((br, 1), lambda i, p, j: (i, 0)),
@@ -174,7 +152,7 @@ def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret,
             jax.ShapeDtypeStruct((T, N, gi * br, E1), jnp.float32),
         ],
         interpret=interpret,
-    )(xpad[:, None], xpad[None, :], ypad)
+    )(xc, xr, A, yoff)
     # Kernel layout keeps (br, E1) matmul tiles contiguous; callers want
     # query-major (rows, T, …) for the batched Cholesky solve.
     G = jnp.transpose(G, (2, 0, 1, 3))[:rows]  # (rows, T, E1, E1)
@@ -193,26 +171,11 @@ def smap_gram(
     exclude_self: bool = True,
     block: tuple[int, int] = (128, 1024),
     interpret: bool = False,
-    cache_phase1: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Streaming weighted Gram/moments → (G (rows,T,E+1,E+1), M (rows,T,N,E+1)).
 
     Semantics identical to ``ref.smap_gram`` (see its docstring); Y is the
     (N, L) target panel (Y = x[None] for self-prediction).
-
-    ``cache_phase1`` resolves the ROADMAP S-Map follow-on (a): instead of
-    recomputing each O(E·br·bc) distance block in the phase-1 sweep,
-    phase 0 spills its √acc rows to a (br, rows) f32 VMEM scratch that
-    phase 1 reads back (bit-equal outputs — same arithmetic either way).
-    Measured (Pallas interpreter at L=512, E=6, |θ|=4, N=2,
-    block=(64, 256); the container has no TPU, so this measures executed
-    ops, not MXU/VPU overlap): recompute 9.8 ms, cache 11.8 ms — the
-    cache LOSES even before hardware effects, and on a real TPU the
-    recompute is VPU work that overlaps the phase-1 MXU matmuls while
-    the scratch costs 4·br·rows bytes of VMEM, capping the library near
-    rows ≈ 16k at br=128 before the scratch alone eats half of VMEM.
-    Default therefore stays ``False`` (recompute); the knob exists for
-    TPU profiling to revisit.
     """
     L = x.shape[-1]
     num_embedded(L, E, tau)  # raises on too-short series
@@ -220,5 +183,4 @@ def smap_gram(
         raise ValueError("library/target series length mismatch")
     return _call(x, Y, E=E, tau=tau, Tp=Tp,
                  thetas=tuple(float(t) for t in thetas),
-                 exclude_self=exclude_self, block=block, interpret=interpret,
-                 cache_phase1=cache_phase1)
+                 exclude_self=exclude_self, block=block, interpret=interpret)

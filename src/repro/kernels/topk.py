@@ -83,7 +83,7 @@ def topk_select(
         functools.partial(_kernel, k=k, br=br, Lp=Lp, exclude_self=exclude_self),
         grid=(pl.cdiv(Lp, br),),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),  # dynamic candidate cap
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # dynamic candidate cap
             pl.BlockSpec((br, Lp), lambda i: (i, 0)),
         ],
         out_specs=[
@@ -99,26 +99,45 @@ def topk_select(
     return dk, ik
 
 
-def _merge_kbest(cand_d, cand_i, k):
+def merge_kbest(cand_d, cand_i, k, *, width=None, big=_BIG_I):
     """k passes of (min, min-global-index-on-ties, retire-by-index).
 
-    Identical discipline to ``knn_multi_e``'s streaming merge: selecting
-    the minimum *global index* among distance ties makes the streamed
-    result bit-identical to a stable full-row partial sort for any
-    column tiling, and retiring the winner by index (clearing both
-    arrays) keeps rows with < k valid candidates from re-emitting one.
+    The streaming k-best merge every column-tiled kernel shares
+    (``knn_batch``, ``knn_multi_e``, ``knn_append``, the multi-cap
+    sweep below). Selecting the minimum *global index* among distance
+    ties makes the streamed result bit-identical to a stable full-row
+    partial sort for any column tiling. The winner is retired by index,
+    clearing BOTH arrays: inf-distance entries can't be retired via
+    distance alone (they're already inf), and an un-cleared index would
+    win every later inf-tie — re-emitting the same index on rows with
+    < k valid candidates. Indices are unique per row across the
+    candidates (``big`` only fills interchangeable padding).
+
+    Returns (br, width) tables (width defaults to k) whose slots ≥ k are
+    inf / ``big``. The passes run as a ``fori_loop``, not unrolled: the
+    body is compiled once, which keeps Mosaic's compile time and VMEM
+    stack flat in k (unrolled at k = 22 across 20 multi-E levels it
+    took minutes and overflowed the scoped VMEM limit).
     """
-    best_d, best_i = [], []
-    for _ in range(k):
+    width = k if width is None else width
+    br = cand_d.shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (br, width), 1)
+
+    def one_pass(p, carry):
+        cand_d, cand_i, best_d, best_i = carry
         m = jnp.min(cand_d, axis=1, keepdims=True)
-        sel = jnp.where(cand_d == m, cand_i, _BIG_I)
+        sel = jnp.where(cand_d == m, cand_i, big)
         bi = jnp.min(sel, axis=1, keepdims=True)
-        best_d.append(m)
-        best_i.append(bi)
         removed = cand_i == bi
-        cand_d = jnp.where(removed, jnp.inf, cand_d)
-        cand_i = jnp.where(removed, _BIG_I, cand_i)
-    return jnp.concatenate(best_d, axis=1), jnp.concatenate(best_i, axis=1)
+        return (jnp.where(removed, jnp.inf, cand_d),
+                jnp.where(removed, big, cand_i),
+                jnp.where(slot == p, m, best_d),
+                jnp.where(slot == p, bi, best_i))
+
+    init = (cand_d, cand_i, jnp.full((br, width), jnp.inf, jnp.float32),
+            jnp.full((br, width), big, jnp.int32))
+    _, _, best_d, best_i = jax.lax.fori_loop(0, k, one_pass, init)
+    return best_d, best_i
 
 
 def _sizes_kernel(d_ref, dk_ref, ik_ref, run_d, run_i, *, k, caps, br, bc,
@@ -153,7 +172,7 @@ def _sizes_kernel(d_ref, dk_ref, ik_ref, run_d, run_i, *, k, caps, br, bc,
             snap = jnp.where(invalid | (cols > m), jnp.inf, d)
             cand_d = jnp.concatenate([snap, run_d[...]], axis=1)
             cand_i = jnp.concatenate([cols, run_i[...]], axis=1)
-            bd, bi = _merge_kbest(cand_d, cand_i, k)
+            bd, bi = merge_kbest(cand_d, cand_i, k)
             dk_ref[s] = jnp.sqrt(jnp.maximum(bd, 0.0))
             ik_ref[s] = bi
     # Main stream: fold the full block (masked to the global cap) into
@@ -162,7 +181,7 @@ def _sizes_kernel(d_ref, dk_ref, ik_ref, run_d, run_i, *, k, caps, br, bc,
         [jnp.where(invalid | (cols > caps[-1]), jnp.inf, d), run_d[...]],
         axis=1)
     cand_i = jnp.concatenate([cols, run_i[...]], axis=1)
-    run_d[...], run_i[...] = _merge_kbest(cand_d, cand_i, k)
+    run_d[...], run_i[...] = merge_kbest(cand_d, cand_i, k)
 
 
 @functools.partial(

@@ -161,6 +161,11 @@ def test_chaos_scenario_liveness_and_linearizability(seed, tmp_path):
         for kind, fut, ticket, j, E in submitted:
             try:
                 res = fut.result(timeout=120)
+            except DeadlineExceeded:
+                # Caught first: it subclasses TimeoutError, which is also
+                # concurrent.futures.TimeoutError on Python ≥ 3.11.
+                outcomes.append((kind, ticket, j, E, None))
+                continue
             except _FutureTimeout:
                 pytest.fail(f"hung future: ticket {ticket} ({kind})")
             except Exception as exc:

@@ -6,8 +6,13 @@ one workflow on a synthetic causal system, validating the paper's
 qualitative claims end-to-end rather than per-module.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from repro import core
 from repro.data import timeseries as ts
@@ -55,3 +60,21 @@ def test_tp_horizon_pipeline():
     r2 = float(core.cross_map(jnp.asarray(y), jnp.asarray(x), E=2, Tp=2))
     assert r0 > 0.8
     assert r2 < r0 + 0.05  # horizon can't *help*
+
+
+@pytest.mark.parametrize("module", [
+    "repro.edm", "repro.serving", "repro.distributed", "repro.kernels.ops"])
+def test_import_starts_no_backend(module):
+    """Importing the package must not claim a device: a parent process
+    that imports it may still hand the chip to a child (one process per
+    chip). A module-level jnp constant would start the backend."""
+    prog = (f"import {module}\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('NO_BACKEND')")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")))
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "NO_BACKEND" in out.stdout
