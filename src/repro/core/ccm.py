@@ -341,43 +341,41 @@ def drive_batched(Nl: int, B: int, launch, *, start: int = 0,
     if start >= Nl:  # resumed run with no tiles left: nothing to drive
         return None
     out = pending = None
-    # Always-on per-launch metrics (dict/int ops, no sink required):
-    # end-to-end launch latency (dispatch → rows on host), pairs/s
-    # numerator, and the launch count. The tile *event* and the drive
-    # span are emitted only when a sink is live.
-    lat_hist = telemetry.histogram("edm_launch_latency_seconds")
+    # Always-on per-launch metrics (float/int adds, no sink required):
+    # the pairs/s numerator, the launch count, and the host seconds spent
+    # inside ``launch`` (building and enqueuing it). The launch/land
+    # spans are emitted only when telemetry is live.
     pairs = telemetry.counter("edm_pairs_total")
     launches = telemetry.counter("edm_launches")
+    dispatch_s = telemetry.counter("edm_dispatch_seconds")
 
     def land(pending):
         nonlocal out
-        (pa, pb), arr, t_disp = pending
-        t_land = time.perf_counter()
-        block = np.asarray(arr)       # the device sync point
-        t_done = time.perf_counter()
-        if out is None:
-            out = np.empty((Nl,) + block.shape[1:], block.dtype)
-        out[pa:pb] = block[: pb - pa]
-        lat_hist.observe(t_done - t_disp)
+        (pa, pb), arr = pending
+        with telemetry.span("engine.land", a=pa, b=pb):
+            block = np.asarray(arr)       # the device sync point
+            if out is None:
+                out = np.empty((Nl,) + block.shape[1:], block.dtype)
+            out[pa:pb] = block[: pb - pa]
         pairs.inc(int(block[: pb - pa].size))
-        if telemetry.active():
-            telemetry.event("engine.tile", a=pa, b=pb,
-                            latency_s=t_done - t_disp,
-                            sync_s=t_done - t_land)
         if on_block is not None:
             on_block(pa, pb, block[: pb - pa])
 
     with telemetry.span("engine.drive", Nl=Nl, B=B, start=start):
         for a in range(start, Nl, B):
+            b = min(a + B, Nl)
             if monitor is not None:
                 monitor.start()
             launches.inc()
-            cur = launch(a, min(a + B, Nl), B)
+            with telemetry.span("engine.launch", a=a, b=b, B=B):
+                t0 = time.perf_counter()
+                cur = launch(a, b, B)
+                dispatch_s.inc(time.perf_counter() - t0)
             if pending is not None:
                 land(pending)
                 if monitor is not None:
                     monitor.stop(pending[0][0])
-            pending = ((a, min(a + B, Nl)), cur, time.perf_counter())
+            pending = ((a, b), cur)
         if monitor is not None:
             monitor.start()
         land(pending)

@@ -118,8 +118,8 @@ def _egroup_layout(E_opt, S: int):
     return perm, keep, tuple(segs)
 
 
-def _local_block(libs, tgts, *, E, tau, Tp, rows, off, hard_max, impl,
-                 batch_libs=None, budget_mb=None):
+def _local_block(libs, tgts, *, E, tau, Tp, impl, batch_libs=None,
+                 budget_mb=None):
     """ρ tile for (local libraries × local targets): (nl, nt).
 
     The per-shard inner engine is library-batched (ISSUE 5): local
@@ -135,6 +135,8 @@ def _local_block(libs, tgts, *, E, tau, Tp, rows, off, hard_max, impl,
 
     nl, L = libs.shape
     Lp = num_embedded(L, E, tau)
+    rows, off = pred_rows(L, E, tau, Tp), embed_offset(E, tau, Tp)
+    hard_max = Lp - 1 - max(Tp, 0)
     B = batch_libs if batch_libs is not None else auto_batch_libs(
         Lp, nl, budget_mb)
     B = max(1, min(int(B), nl))
@@ -150,6 +152,58 @@ def _local_block(libs, tgts, *, E, tau, Tp, rows, off, hard_max, impl,
 
     out = jax.lax.map(one_batch, libs.reshape(nb, B, L))
     return out.reshape(nb * B, -1)[:nl]
+
+
+def _segmented_map(block_fn, segs, *, mesh, lib_axes, tgt_axes,
+                   curves=False):
+    """SPMD map of (libraries × targets) over the mesh, one static
+    E-segment structure ``segs = ((E, width), ...)`` on every shard.
+
+    ``block_fn(E)`` maps (local libs, local target segment) to a (nl, w)
+    ρ tile — or, with ``curves=True``, to a (S, nl, w) convergence tile
+    whose leading size axis is replicated; the segments' tiles are
+    concatenated along the target axis, which stays minor.
+    """
+
+    def local(libs, tgts):
+        outs, o = [], 0
+        for Eg, w in segs:
+            seg = jax.lax.slice_in_dim(tgts, o, o + w, axis=0)
+            outs.append(block_fn(Eg)(libs, seg))
+            o += w
+        return jnp.concatenate(outs, axis=-1)
+
+    return _shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(lib_axes, None), P(tgt_axes, None)),
+        out_specs=P(None, lib_axes, tgt_axes) if curves
+        else P(lib_axes, tgt_axes),
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _ccm_program(segs, *, tau, Tp, impl, batch_libs, budget_mb, mesh,
+                 lib_axes, tgt_axes):
+    """The SPMD program of ``sharded_ccm_matrix`` as a named ``jax.jit``.
+
+    Kept per (E-segments, engine settings, mesh), so a call on shapes
+    seen before compiles nothing, and the device trace names the program
+    (``jit_sharded_ccm_program``) instead of an anonymous eager map.
+    """
+
+    def block_fn(E):
+        return functools.partial(_local_block, E=E, tau=tau, Tp=Tp,
+                                 impl=impl, batch_libs=batch_libs,
+                                 budget_mb=budget_mb)
+
+    mapped = _segmented_map(block_fn, segs, mesh=mesh, lib_axes=lib_axes,
+                            tgt_axes=tgt_axes)
+
+    def sharded_ccm_program(X_lib, X_tgt):
+        return mapped(X_lib, X_tgt)
+
+    return jax.jit(sharded_ccm_program)
 
 
 def sharded_ccm_matrix(
@@ -181,46 +235,41 @@ def sharded_ccm_matrix(
     ``lib_axes``); returns a host (N_lib, N_tgt) np.ndarray in the
     original target order. ``batch_libs`` / ``batch_budget_mb`` size the
     per-shard library-batched inner engine (see ``_local_block``).
+    Both modes run one cached program per (E-segments, shapes, mesh).
     """
     L = X_lib.shape[-1]
     if X_tgt.shape[-1] != L:
         raise ValueError("library/target series length mismatch")
     if (E is None) == (E_opt is None):
         raise ValueError("pass exactly one of E= or E_opt=")
+    lib_axes, tgt_axes = tuple(lib_axes), tuple(tgt_axes)
 
-    def block_fn(Eb):
-        return functools.partial(
-            _local_block, E=Eb, tau=tau, Tp=Tp,
-            rows=pred_rows(L, Eb, tau, Tp), off=embed_offset(Eb, tau, Tp),
-            hard_max=num_embedded(L, Eb, tau) - 1 - max(Tp, 0), impl=impl,
-            batch_libs=batch_libs, budget_mb=batch_budget_mb)
+    program = functools.partial(
+        _ccm_program, tau=tau, Tp=Tp, impl=impl, batch_libs=batch_libs,
+        budget_mb=batch_budget_mb, mesh=mesh, lib_axes=lib_axes,
+        tgt_axes=tgt_axes)
 
     telemetry.counter("edm_sharded_launches").inc()
     with telemetry.span("sharded.ccm_matrix", N_lib=int(X_lib.shape[0]),
                         N_tgt=int(X_tgt.shape[0]), fixed_E=E is not None):
         if E_opt is None:
-            mapped = _shard_map(
-                block_fn(E),
-                mesh=mesh,
-                in_specs=(P(lib_axes, None), P(tgt_axes, None)),
-                out_specs=P(lib_axes, tgt_axes),
-            )
-            return mapped(X_lib, X_tgt)
-        return _egrouped_matrix(X_lib, X_tgt, block_fn, E_opt=E_opt,
+            nt = X_tgt.shape[0] // mesh_axes_size(mesh, tgt_axes)
+            return program(((int(E), nt),))(X_lib, X_tgt)
+        return _egrouped_matrix(X_lib, X_tgt, program, E_opt=E_opt,
                                 mesh=mesh, lib_axes=lib_axes,
                                 tgt_axes=tgt_axes, layout=layout)
 
 
-def _egrouped_matrix(X_lib, X_tgt, block_fn, *, E_opt, mesh, lib_axes,
+def _egrouped_matrix(X_lib, X_tgt, program, *, E_opt, mesh, lib_axes,
                      tgt_axes, curves: bool = False,
                      layout=None) -> np.ndarray:
     """Shared E-grouped driver: per-shard static E-segments, one SPMD
     program, no collectives; host unpermute at result delivery.
 
-    ``block_fn(E)`` maps (local libs, local target segment) to a
-    (nl, w) ρ tile — or, with ``curves=True``, to a (S, nl, w)
-    convergence tile whose leading size axis is replicated (the
-    ``sharded_ccm_convergence`` layout); targets stay the minor axis.
+    ``program(segs)`` is the SPMD map of one static segment structure
+    (``_segmented_map``): (padded libraries, laid-out targets) to the
+    (nl, nt) ρ matrix — or, with ``curves=True``, the (S, nl, nt)
+    convergence grid.
 
     ``E_opt`` (and the permutation derived from it) stays on device
     until result delivery — the host sees only the static layout
@@ -238,23 +287,7 @@ def _egrouped_matrix(X_lib, X_tgt, block_fn, *, E_opt, mesh, lib_axes,
                           if layout is None else layout)
     Xl = pad_to_multiple(X_lib, S_l, axis=0)
     Xt = jnp.take(jnp.asarray(X_tgt), perm_d, axis=0)
-
-    def local(libs, tgts):
-        outs, o = [], 0
-        for Eg, w in segs:
-            seg = jax.lax.slice_in_dim(tgts, o, o + w, axis=0)
-            outs.append(block_fn(Eg)(libs, seg))
-            o += w
-        return jnp.concatenate(outs, axis=-1)
-
-    mapped = _shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(lib_axes, None), P(tgt_axes, None)),
-        out_specs=P(None, lib_axes, tgt_axes) if curves
-        else P(lib_axes, tgt_axes),
-    )
-    R = np.asarray(mapped(Xl, Xt))
+    R = np.asarray(program(segs)(Xl, Xt))
     perm = np.asarray(perm_d)  # delivered WITH the results, not before
     if curves:
         rho = np.zeros((R.shape[0], N_lib, N_tgt), np.float32)
@@ -335,9 +368,13 @@ def sharded_ccm_convergence(
                 out_specs=P(None, lib_axes, tgt_axes),
             )
             return mapped(X_lib, X_tgt)
-        return _egrouped_matrix(X_lib, X_tgt, block_fn, E_opt=E_opt,
-                                mesh=mesh, lib_axes=lib_axes,
-                                tgt_axes=tgt_axes, curves=True)
+        return _egrouped_matrix(
+            X_lib, X_tgt,
+            functools.partial(_segmented_map, block_fn, mesh=mesh,
+                              lib_axes=lib_axes, tgt_axes=tgt_axes,
+                              curves=True),
+            E_opt=E_opt, mesh=mesh, lib_axes=lib_axes, tgt_axes=tgt_axes,
+            curves=True)
 
 
 def sharded_optimal_E(
@@ -474,9 +511,12 @@ def sharded_smap_matrix(
                 out_specs=P(lib_axes, tgt_axes),
             )
             return mapped(X_lib, X_tgt)
-        return _egrouped_matrix(X_lib, X_tgt, block_fn, E_opt=E_opt,
-                                mesh=mesh, lib_axes=lib_axes,
-                                tgt_axes=tgt_axes, layout=layout)
+        return _egrouped_matrix(
+            X_lib, X_tgt,
+            functools.partial(_segmented_map, block_fn, mesh=mesh,
+                              lib_axes=lib_axes, tgt_axes=tgt_axes),
+            E_opt=E_opt, mesh=mesh, lib_axes=lib_axes, tgt_axes=tgt_axes,
+            layout=layout)
 
 
 def ccm_step(X: jax.Array, *, E: int, tau: int, mesh: jax.sharding.Mesh,

@@ -517,8 +517,8 @@ class MatrixRunner:
             "oom_backoff": self.oom_trail,
             "invalid_series": self.invalid_series,
             # the whole process-local metrics registry, Prometheus text
-            # exposition format (edm_pairs_total, the per-launch latency
-            # histogram, cache/run counters, ...)
+            # exposition format (edm_pairs_total, edm_launches and
+            # edm_dispatch_seconds, cache/run counters, ...)
             "metrics_prom": telemetry.render_prom(),
         }
         tmp = os.path.join(self.dir, "report.json.tmp")

@@ -580,26 +580,25 @@ class EDM:
                 if not self._pair_invalid(li, ti)]
         if not live:
             return out
-        Lp = num_embedded(self.data.L, E, c.tau)
-        cap = Lp - max(c.Tp_cross, 0)
-        k = E + 1
-        hit = (self._master(E) if c.cache and c.mesh is None else None)
-        if hit is None or not master_slack_covers(
-                (cap,), Lp=Lp, k=k, k_master=hit[2]):
-            for j, li, ti in live:
-                out[j] = self.ccm(li, ti, E=E)
-            return out
-        libs = sorted({li for _, li, _ in live})
-        lpos = {li: i for i, li in enumerate(libs)}
-        la = jnp.asarray(libs)
-        with telemetry.span("session.ccm_batch", pairs=len(idx),
-                            libs=len(libs), E=E):
-            self._plan_event("ccm")
+        with telemetry.span("session.ccm_batch", pairs=len(idx), E=E) as sp:
+            Lp = num_embedded(self.data.L, E, c.tau)
+            cap = Lp - max(c.Tp_cross, 0)
+            k = E + 1
+            hit = (self._master(E) if c.cache and c.mesh is None else None)
+            if hit is None or not master_slack_covers(
+                    (cap,), Lp=Lp, k=k, k_master=hit[2]):
+                for j, li, ti in live:
+                    out[j] = self.ccm(li, ti, E=E)
+                return out
+            libs = sorted({li for _, li, _ in live})
+            sp.annotate(libs=len(libs))
+            lpos = {li: i for i, li in enumerate(libs)}
+            la = jnp.asarray(libs)
             g = np.asarray(ccm_group_from_master_batched(
                 self.data.panel[la], hit[1][la, E - 1], self.data.panel,
                 E=E, tau=c.tau, Tp=c.Tp_cross, k=k, impl=self._impl))
-        for j, li, ti in live:
-            out[j] = g[lpos[li], ti]
+            for j, li, ti in live:
+                out[j] = g[lpos[li], ti]
         self._bump("ccm_batch_pairs", len(live))
         return out
 

@@ -75,7 +75,7 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E, k, mx, br, bc, gj,
     jax.jit,
     static_argnames=("E", "tau", "k", "mx", "exclude_self", "block",
                      "interpret"))
-def _call(X, *, E, tau, k, mx, exclude_self, block, interpret):
+def knn_batch(X, *, E, tau, k, mx, exclude_self, block, interpret):
     B, L = X.shape
     Lp = num_embedded(L, E, tau)
     br = max(8, min(block[0], Lp))
@@ -104,6 +104,7 @@ def _call(X, *, E, tau, k, mx, exclude_self, block, interpret):
             jax.ShapeDtypeStruct((B, Lp, k), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_batch",
     )(xc, xr)
 
 
@@ -130,5 +131,5 @@ def all_knn_batch(
     Lp = num_embedded(L, E, tau)  # raises on too-short series
     k = E + 1 if k is None else int(k)
     mx = Lp - 1 if max_idx is None else min(int(max_idx), Lp - 1)
-    return _call(X, E=E, tau=tau, k=k, mx=mx, exclude_self=exclude_self,
-                 block=block, interpret=interpret)
+    return knn_batch(X, E=E, tau=tau, k=k, mx=mx, exclude_self=exclude_self,
+                     block=block, interpret=interpret)

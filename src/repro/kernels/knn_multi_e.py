@@ -101,7 +101,7 @@ def _kernel(xc_ref, xr_ref, dk_ref, ik_ref, *, E_max, ks, mxs,
     jax.jit,
     static_argnames=("E_max", "tau", "ks", "mxs", "exclude_self", "block",
                      "interpret"))
-def _call(x, *, E_max, tau, ks, mxs, exclude_self, block, interpret):
+def knn_multi_e(x, *, E_max, tau, ks, mxs, exclude_self, block, interpret):
     L = x.shape[-1]
     k_max = max(ks)
     br = max(8, min(block[0], L))
@@ -130,6 +130,7 @@ def _call(x, *, E_max, tau, ks, mxs, exclude_self, block, interpret):
             jax.ShapeDtypeStruct((E_max, L, k_max), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_multi_e",
     )(xc, xr)
     return pad_multi_e_tables(dk, ik, E_max=E_max, tau=tau, ks=ks)
 
@@ -154,5 +155,6 @@ def all_knn_multi_e(
     num_embedded(L, E_max, tau)  # raises on too-short series
     ks = multi_e_ks(E_max, k)
     mxs = multi_e_max_idx(L, E_max, tau, max_idx)
-    return _call(x, E_max=E_max, tau=tau, ks=ks, mxs=mxs,
-                 exclude_self=exclude_self, block=block, interpret=interpret)
+    return knn_multi_e(x, E_max=E_max, tau=tau, ks=ks, mxs=mxs,
+                       exclude_self=exclude_self, block=block,
+                       interpret=interpret)

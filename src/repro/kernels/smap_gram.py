@@ -110,9 +110,28 @@ def _kernel(xc_ref, xr_ref, a_ref, y_ref, ds_ref, g_ref, m_ref, *, E,
     jax.jit,
     static_argnames=("E", "tau", "Tp", "thetas", "exclude_self", "block",
                      "interpret"))
-def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret):
+def smap_gram(
+    x: jax.Array,
+    Y: jax.Array,
+    *,
+    E: int,
+    tau: int = 1,
+    Tp: int = 1,
+    thetas: tuple[float, ...],
+    exclude_self: bool = True,
+    block: tuple[int, int] = (128, 1024),
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Streaming weighted Gram/moments → (G (rows,T,E+1,E+1), M (rows,T,N,E+1)).
+
+    Semantics identical to ``ref.smap_gram`` (see its docstring); Y is the
+    (N, L) target panel (Y = x[None] for self-prediction). ``thetas`` is
+    a tuple: it is static, one program per θ set.
+    """
     L = x.shape[-1]
-    rows = num_embedded(L, E, tau) - max(Tp, 0)
+    rows = num_embedded(L, E, tau) - max(Tp, 0)  # raises on too-short series
+    if Y.shape[-1] != L:
+        raise ValueError("library/target series length mismatch")
     off = (E - 1) * tau + Tp
     E1 = E + 1
     T = len(thetas)
@@ -152,35 +171,10 @@ def _call(x, Y, *, E, tau, Tp, thetas, exclude_self, block, interpret):
             jax.ShapeDtypeStruct((T, N, gi * br, E1), jnp.float32),
         ],
         interpret=interpret,
+        name="smap_gram",
     )(xc, xr, A, yoff)
     # Kernel layout keeps (br, E1) matmul tiles contiguous; callers want
     # query-major (rows, T, …) for the batched Cholesky solve.
     G = jnp.transpose(G, (2, 0, 1, 3))[:rows]  # (rows, T, E1, E1)
     M = jnp.transpose(M, (2, 0, 1, 3))[:rows]  # (rows, T, N, E1)
     return G, M
-
-
-def smap_gram(
-    x: jax.Array,
-    Y: jax.Array,
-    *,
-    E: int,
-    tau: int = 1,
-    Tp: int = 1,
-    thetas: tuple[float, ...],
-    exclude_self: bool = True,
-    block: tuple[int, int] = (128, 1024),
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Streaming weighted Gram/moments → (G (rows,T,E+1,E+1), M (rows,T,N,E+1)).
-
-    Semantics identical to ``ref.smap_gram`` (see its docstring); Y is the
-    (N, L) target panel (Y = x[None] for self-prediction).
-    """
-    L = x.shape[-1]
-    num_embedded(L, E, tau)  # raises on too-short series
-    if Y.shape[-1] != L:
-        raise ValueError("library/target series length mismatch")
-    return _call(x, Y, E=E, tau=tau, Tp=Tp,
-                 thetas=tuple(float(t) for t in thetas),
-                 exclude_self=exclude_self, block=block, interpret=interpret)

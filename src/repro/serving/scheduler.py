@@ -78,8 +78,11 @@ Telemetry: ``serve_queue_depth`` / ``serve_queued_bytes`` /
 ``serve_latency_ms_<op>`` histograms, ``serve_requests`` /
 ``serve_batches`` / ``serve_launches_saved`` / ``serve_evictions`` /
 ``serve_worker_deaths`` / ``serve_worker_revives`` / ``serve_rejected``
-/ ``serve_deadline_exceeded`` / ``serve_quarantined`` counters, and a
-span per batch with per-request events.
+/ ``serve_deadline_exceeded`` / ``serve_quarantined`` counters, the
+queue-wait and execution clocks ``serve_queue_wait_seconds`` over
+``serve_claimed`` (submit to claim, per request taken into a batch) and
+``serve_exec_seconds`` over ``serve_batches`` (per executed batch), and
+a span per batch with per-request events.
 """
 
 from __future__ import annotations
@@ -560,6 +563,9 @@ class Scheduler:
                 pq.q = rest
             self._queued_bytes -= (sum(r.cost for r in batch)
                                    + sum(r.cost for r in expired))
+            telemetry.counter("serve_queue_wait_seconds").inc(
+                sum(now - r.t_submit for r in batch))
+            telemetry.counter("serve_claimed").inc(len(batch))
             telemetry.gauge("serve_queue_depth").set(
                 sum(len(q.q) for q in self._queues.values()))
             telemetry.gauge("serve_queued_bytes").set(self._queued_bytes)
@@ -688,6 +694,7 @@ class Scheduler:
             else:
                 r.future.set_result(res)
         telemetry.counter("serve_batches").inc()
+        telemetry.counter("serve_exec_seconds").inc(ms / 1e3)
         self._note_batch_success(pq)
         self._after_batch(entry)
 

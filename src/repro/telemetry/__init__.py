@@ -14,7 +14,7 @@ through this one subsystem:
   disabled fast path costs one attribute read per call site.
 * **Counters / gauges / histograms** — a process-local metrics registry
   (``counter("edm_pairs_total")``, ``gauge("edm_batch_libs_effective")``,
-  ``histogram("edm_launch_latency_seconds")``). Metric updates are plain
+  ``histogram("serve_latency_ms_ccm")``). Metric updates are plain
   dict/int operations and are ALWAYS on — they are the supported
   observation API the tests assert against (via ``Recorder`` deltas),
   replacing monkeypatched kernel shims. ``render_prom()`` exports the
@@ -24,7 +24,10 @@ through this one subsystem:
   tests use), ``JsonlSink`` (one JSON object per line; journaled runs
   attach one under ``run_dir/telemetry/``), and an optional
   ``jax.profiler.TraceAnnotation`` bridge (``enable_xla_trace()``) so
-  spans line up with XLA traces in TensorBoard/Perfetto.
+  spans line up with XLA traces in TensorBoard/Perfetto. The bridge
+  names each annotation after the span's bare ``name`` (no path, no
+  attributes): the profiler nests host events by time itself, and a
+  trace reader can then match every span, nested or not, by name.
 
 Timing honesty: kernel dispatches (``ops.*``) run at *trace* time inside
 jitted programs, where fencing ``block_until_ready`` is impossible — so
@@ -76,9 +79,9 @@ def disable() -> None:
 
 def enable_xla_trace(on: bool = True) -> None:
     """Bridge spans to ``jax.profiler.TraceAnnotation`` so they appear
-    alongside XLA device traces in TensorBoard/Perfetto. Off by default
-    (the annotation costs a TraceMe per span even without a profiler
-    session attached)."""
+    alongside XLA device traces in TensorBoard/Perfetto, each under the
+    span's bare ``name``. Off by default (the annotation costs a TraceMe
+    per span even without a profiler session attached)."""
     global _xla_trace
     _xla_trace = on
 
@@ -143,10 +146,10 @@ class _Span:
         self.path = f"{parent}/{self.name}" if parent else self.name
         self._token = _span_stack.set(stack + (self,))
         self._ta = None
-        if _xla_trace:  # pragma: no cover - needs a profiler session
+        if _xla_trace:
             try:
                 from jax.profiler import TraceAnnotation
-                self._ta = TraceAnnotation(self.path)
+                self._ta = TraceAnnotation(self.name)
                 self._ta.__enter__()
             except Exception:
                 self._ta = None
@@ -156,7 +159,7 @@ class _Span:
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
-        if self._ta is not None:  # pragma: no cover
+        if self._ta is not None:
             self._ta.__exit__(*exc)
         _span_stack.reset(self._token)
         ev = {"type": "span", "name": self.name, "path": self.path,

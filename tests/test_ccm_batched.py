@@ -223,6 +223,44 @@ def test_sharded_local_block_batching_matches_unbatched():
                                rtol=1e-5, atol=1e-6)
 
 
+def test_sharded_ccm_program_is_named_and_compiled_once():
+    """The sharded matrix program is one named, cached jit: a repeat call
+    on the same shapes compiles nothing in either E mode, and the trace
+    sees it as ``jit_sharded_ccm_program``."""
+    import jax
+
+    from repro.distributed import make_ccm_mesh, sharded_ccm_matrix
+    from repro.distributed.sharded_ccm import _ccm_program
+    X = _panel(6, 220)
+    mesh = make_ccm_mesh((1, 1), ("data", "model"))
+    E_opt = np.array([2, 3, 2, 3, 3, 2], np.int32)
+
+    def both():
+        return (np.asarray(sharded_ccm_matrix(X, X, E=2, mesh=mesh,
+                                              impl="ref")),
+                sharded_ccm_matrix(X, X, E_opt=E_opt, mesh=mesh, impl="ref"))
+
+    first = both()
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        again = both()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    prog = _ccm_program(((2, 6),), tau=1, Tp=0, impl="ref", batch_libs=None,
+                        budget_mb=None, mesh=mesh, lib_axes=("data",),
+                        tgt_axes=("model",))
+    assert "jit_sharded_ccm_program" in prog.lower(X, X).as_text()
+
+
 def test_egroup_layout_device_side_matches_host_reference():
     """The device-built permutation equals the old host-side layout:
     groups ascending by E, members in index order, each padded to a

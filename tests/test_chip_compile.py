@@ -15,18 +15,20 @@ every worker imports this file.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import ccm
+from repro.edm.plan import panel_master
 from repro.kernels import ref
 from repro.kernels.knn_append import _master_append
-from repro.kernels.knn_batch import _call as knn_batch_call
-from repro.kernels.knn_multi_e import _call as multi_e_call
+from repro.kernels.knn_batch import knn_batch
+from repro.kernels.knn_multi_e import knn_multi_e
 from repro.kernels.lookup import lookup_rho
-from repro.kernels.smap_gram import _call as smap_gram_call
+from repro.kernels.smap_gram import smap_gram
 from repro.kernels.topk import topk_select_sizes
 
 HBM_BYTES = 16 * 10**9  # one TPU v5e chip
@@ -77,7 +79,7 @@ def test_knn_batch_compiles(one_chip, L):
     E = E_MAX
     B = 8 if L < 4096 else 2
     Lp = L - (E - 1)
-    _compile_ok(knn_batch_call.lower(
+    _compile_ok(knn_batch.lower(
         _spec(one_chip, (B, L)), E=E, tau=1, k=E + 1, mx=Lp - 1,
         exclude_self=True, block=(128, 1024), interpret=False))
 
@@ -86,7 +88,7 @@ def test_knn_batch_compiles(one_chip, L):
 def test_knn_multi_e_master_compiles(one_chip, L):
     ks = ref.multi_e_ks(E_MAX, K_MASTER)
     mxs = ref.multi_e_max_idx(L, E_MAX, 1, None)
-    _compile_ok(multi_e_call.lower(
+    _compile_ok(knn_multi_e.lower(
         _spec(one_chip, (L,)), E_max=E_MAX, tau=1, ks=ks, mxs=mxs,
         exclude_self=True, block=(128, 1024), interpret=False))
 
@@ -104,7 +106,7 @@ def test_lookup_rho_compiles(one_chip, L):
 
 @pytest.mark.parametrize("L", LENGTHS)
 def test_smap_gram_compiles(one_chip, L):
-    _compile_ok(smap_gram_call.lower(
+    _compile_ok(smap_gram.lower(
         _spec(one_chip, (L,)), _spec(one_chip, (1, L)), E=6, tau=1, Tp=1,
         thetas=(0.0, 0.1, 0.3, 0.5, 1.0, 2.0, 4.0, 8.0), exclude_self=True,
         block=(128, 1024), interpret=False))
@@ -139,3 +141,29 @@ def test_ccm_group_step_compiles(one_chip, L):
     _compile_ok(ccm._group_step.lower(
         _spec(one_chip, (B, L)), _spec(one_chip, (Nt, L)), E=E, tau=1, Tp=0,
         k=E + 1, impl="pallas"), kernels=2)
+
+
+def _lower_group_step(one_chip):
+    E, L = 10, 1600
+    return ccm._group_step.lower(
+        _spec(one_chip, (2, L)), _spec(one_chip, (16, L)), E=E, tau=1, Tp=0,
+        k=E + 1, impl="pallas")
+
+
+def _lower_panel_master(one_chip):
+    return panel_master.lower(_spec(one_chip, (4, 1600)), E_max=E_MAX,
+                              tau=1, k=K_MASTER, impl="pallas")
+
+
+@pytest.mark.parametrize("lower,kernel", [
+    (_lower_group_step, "knn_batch"),
+    (_lower_panel_master, "knn_multi_e"),
+])
+def test_kernel_programs_are_named(one_chip, lower, kernel):
+    """The program around each kNN kernel carries the kernel's name, which
+    the HLO instruction takes (``%knn_batch.N``, ``%knn_multi_e.N``), so a
+    trace tells the kernels apart by name alone."""
+    funcs = set(re.findall(r"func\.func (?:private |public )?@([\w.]+)",
+                           lower(one_chip).as_text()))
+    assert kernel in funcs
+    assert "_call" not in funcs

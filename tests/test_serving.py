@@ -72,6 +72,28 @@ def test_compatible_ccm_requests_coalesce_into_one_launch(panel):
             rtol=1e-6, atol=1e-6)
 
 
+def test_queue_wait_exec_and_dispatch_counters(panel):
+    """The always-on clocks behind ``/metrics``: one drained batch of n
+    ccm requests claims n requests, and adds queue-wait, execution and
+    engine-dispatch seconds."""
+    with EDMServer(autostart=False) as srv:
+        srv.register_panel("p", panel, E_max=4, cache=True)
+        srv.submit("optimal_E", "p")
+        srv.scheduler.drain_once()
+        with telemetry.record() as rec:
+            futs = [srv.submit("ccm", "p", lib=l, target=t, E=3)
+                    for l, t in PAIRS]
+            assert srv.scheduler.drain_once() == len(PAIRS)
+            for f in futs:
+                f.result(timeout=5)
+    assert rec.counter_delta("serve_claimed") == len(PAIRS)
+    assert rec.counter_delta("serve_batches") == 1
+    for name in ("serve_queue_wait_seconds", "serve_exec_seconds",
+                 "edm_dispatch_seconds"):
+        assert rec.counter_delta(name) > 0, name
+        assert f"# TYPE {name} counter" in telemetry.render_prom()
+
+
 def test_fifo_across_mixed_signatures(panel):
     """A later-arriving compatible request must not leapfrog an earlier
     incompatible one: batches run in head-of-queue arrival order."""

@@ -63,6 +63,53 @@ def test_span_nesting_builds_paths_and_durations():
         assert schema.validate_event(e) == []
 
 
+def _devtrace():
+    """The chip benchmark's trace reduction (it reads the bridged spans)."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "devtrace.py")
+    spec = importlib.util.spec_from_file_location("devtrace", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_xla_bridge_names_nested_spans_by_bare_name(monkeypatch):
+    """Every span reaches the profiler under its bare name (nested ones
+    too, attributes left out), which the trace reduction's span pattern
+    accepts; sinks still get the full path."""
+    import jax
+
+    names = []
+
+    class RecordingAnnotation:
+        def __init__(self, name, **kwargs):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", RecordingAnnotation)
+    telemetry.enable_xla_trace(True)
+    try:
+        with telemetry.record() as rec:
+            EDM(_panel(), EDMConfig(E=3, batch_libs=2)).xmap()
+    finally:
+        telemetry.enable_xla_trace(False)
+    assert {"session.xmap", "engine.drive", "engine.launch",
+            "engine.land"} <= set(names)
+    pattern = _devtrace().TELEMETRY_SPAN
+    assert all(pattern.match(n) for n in names), names
+    assert names.count("engine.launch") == names.count("engine.land") == 3
+    land = rec.spans("engine.land")[0]
+    assert land["path"] == "session.xmap/engine.drive/engine.land"
+    assert land["attrs"] == {"a": 0, "b": 2}
+
+
 def test_enable_activates_without_sinks():
     telemetry.enable()
     try:
@@ -230,8 +277,8 @@ def test_straggler_threshold_config_validation_and_keying():
 
 def test_e2e_journaled_run_produces_all_telemetry_artifacts(tmp_path):
     """The acceptance path in one test: a journaled xmap emits the JSONL
-    span log, folds Prometheus metrics (pairs counter + launch latency
-    histogram) into report.json, counts every pair exactly once, and the
+    span log, folds Prometheus metrics (pairs, launch and dispatch-time
+    counters) into report.json, counts every pair exactly once, and the
     run inspector renders the result from artifacts alone."""
     X = _panel()
     run = tmp_path / "run"
@@ -260,8 +307,11 @@ def test_e2e_journaled_run_produces_all_telemetry_artifacts(tmp_path):
     assert rep["stragglers"]["threshold"] == 5.0  # config threaded through
     prom = rep["metrics_prom"]
     assert "edm_pairs_total" in prom
-    assert "edm_launch_latency_seconds_bucket" in prom
-    assert "edm_launch_latency_seconds_count" in prom
+    assert "# TYPE edm_launches counter" in prom
+    assert "# TYPE edm_dispatch_seconds counter" in prom
+    assert rec.counter_delta("edm_launches") == 3  # ceil(6/2)
+    assert rec.counter_delta("edm_dispatch_seconds") > 0
+    assert "edm_launch_latency_seconds" not in prom
 
     info = edm_inspect.inspect_run(str(run))
     assert info["status"] == "complete"
