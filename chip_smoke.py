@@ -18,7 +18,8 @@ One chip:
      ``knn_batch`` engine).
   b  F1 at its published L = 29484 with N cut to ``F1_N`` (the full
      8520 series would take hours on one chip): one-shot ``xmap`` at
-     ``F1_E``.
+     ``F1_E``, then the share of k-best merge passes ``knn_batch``'s
+     gate lets one library run.
   c  an in-process ``EDMServer`` on the phase-a panel: ``ccm`` requests,
      one append tick, ``ccm`` again; every answer bit-matches a direct
      session on the same chip.
@@ -224,6 +225,15 @@ def phase_b(clock, panel, E, impl_want):
             Tp=c.Tp_cross, impl=impl))
 
     run_phase(clock, "b1 one-shot xmap (knn_batch)", panel.shape, impl, b1)
+
+    def b2():
+        from repro.kernels.knn_batch import knn_batch_merge_share
+
+        share = knn_batch_merge_share(panel[:1], E=E)
+        require(0.0 < share <= 1.0, f"merge share {share}")
+        return f"E={E} merge passes run / k per cell = {share:.4f}"
+
+    run_phase(clock, "b2 knn_batch merge share", panel[:1].shape, impl, b2)
 
 
 def phase_c(clock, grown, L, E_opt, impl_want):

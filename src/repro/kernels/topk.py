@@ -20,6 +20,17 @@ fused into the masking pass.
 ``topk_select_sizes`` is the multi-cap variant behind CCM convergence
 sweeps: ONE column-tiled pass over the distance matrix emits the k-best
 table under every prefix library cap, instead of S full-matrix re-scans.
+
+The column-tiled kernels fold each block into a running k-best with
+``merge_kbest`` (k passes over the block concatenated with the list).
+``merge_kbest_gated`` is its threshold-gated form, used by
+``knn_batch`` once a row's list is full: it admits only candidates
+lexicographically below the list's slot k − 1, runs only as many
+passes as the most-admitting row needs, and shift-inserts each
+extracted entry. Its result is bit-identical to ``merge_kbest`` over
+the concatenation. In a later column block every candidate index
+exceeds every index in the list, so an inf-distance tie goes to the
+held fill index, as in the ungated merge.
 """
 
 from __future__ import annotations
@@ -138,6 +149,65 @@ def merge_kbest(cand_d, cand_i, k, *, width=None, big=_BIG_I):
             jnp.full((br, width), big, jnp.int32))
     _, _, best_d, best_i = jax.lax.fori_loop(0, k, one_pass, init)
     return best_d, best_i
+
+
+def _shift_right(x, fill):
+    """x[:, s − 1] in slot s, ``fill`` in slot 0."""
+    return jnp.concatenate([jnp.full_like(x[:, :1], fill), x[:, :-1]],
+                           axis=1)
+
+
+def merge_kbest_gated(cand_d, cand_i, best_d, best_i, live):
+    """Fold a candidate block into a full sorted k-best, passes on demand.
+
+    ``best_d``/``best_i`` (br, k) hold each row's running k best sorted
+    by (distance, index); ``cand_*`` (br, bc) is the next column block.
+    The gate: only a candidate lexicographically below the row's slot
+    k − 1, ``(d < d_k) | (d == d_k & i < i_k)``, can reach the merged
+    top k; every other one becomes (inf, ``_BIG_I``). The block then runs
+    P = min(k, most qualifying candidates in any ``live`` row) passes of
+    ``merge_kbest``'s (min, min-index-on-ties, retire-by-index), each
+    over the block alone, and shift-inserts the extracted (m, bi) at its
+    lexicographic rank in the running list, so slot k − 1 drops off.
+    Passes extract in ascending (d, index) order, so after P of them
+    the list is the k smallest of (running ∪ block): bit-identical to
+    ``merge_kbest`` over their concatenation. A row with fewer
+    qualifying candidates than P extracts (inf, ``_BIG_I``), which
+    precedes no slot and inserts nothing. Rows where the (br, 1) mask
+    ``live`` is false (tile padding) still merge but do not raise P.
+
+    Returns (best_d, best_i, P), P an int32 scalar.
+    """
+    br, k = best_d.shape
+    d_k, i_k = best_d[:, k - 1:k], best_i[:, k - 1:k]
+    q = (cand_d < d_k) | ((cand_d == d_k) & (cand_i < i_k))
+    count = jnp.sum(q.astype(jnp.int32), axis=1, keepdims=True)
+    count = jnp.where(live, count, 0)
+    passes = jnp.minimum(jnp.max(count), k)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (br, k), 1)
+
+    def one_pass(_, carry):
+        cand_d, cand_i, best_d, best_i = carry
+        m = jnp.min(cand_d, axis=1, keepdims=True)
+        sel = jnp.where(cand_d == m, cand_i, _BIG_I)
+        bi = jnp.min(sel, axis=1, keepdims=True)
+        removed = cand_i == bi
+        # Slots the new entry precedes: a suffix, since the list is sorted.
+        after = (m < best_d) | ((m == best_d) & (bi < best_i))
+        pos = k - jnp.sum(after.astype(jnp.int32), axis=1, keepdims=True)
+        return (jnp.where(removed, jnp.inf, cand_d),
+                jnp.where(removed, _BIG_I, cand_i),
+                jnp.where(slot < pos, best_d,
+                          jnp.where(slot == pos, m,
+                                    _shift_right(best_d, jnp.inf))),
+                jnp.where(slot < pos, best_i,
+                          jnp.where(slot == pos, bi,
+                                    _shift_right(best_i, _BIG_I))))
+
+    init = (jnp.where(q, cand_d, jnp.inf), jnp.where(q, cand_i, _BIG_I),
+            best_d, best_i)
+    _, _, best_d, best_i = jax.lax.fori_loop(0, passes, one_pass, init)
+    return best_d, best_i, passes
 
 
 def _sizes_kernel(d_ref, dk_ref, ik_ref, run_d, run_i, *, k, caps, br, bc,
