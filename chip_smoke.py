@@ -21,8 +21,10 @@ One chip:
      ``F1_E``, then the share of k-best merge passes ``knn_batch``'s
      gate lets one library run.
   c  an in-process ``EDMServer`` on the phase-a panel: ``ccm`` requests,
-     one append tick, ``ccm`` again; every answer bit-matches a direct
-     session on the same chip.
+     then ``APPENDS`` appends of 8 samples with ``ccm`` between them;
+     the first append sizes the panel's capacity, nothing compiles
+     after the requests that follow it, and every answer bit-matches a
+     direct session at the same capacity on the same chip.
 
 Phases a and b are checked against ``kernels/ref.py`` run on the same
 chip, on a sample of library rows (see ``RHO_TOL``).
@@ -74,6 +76,7 @@ FISH = (154, 1600)  # Fish1_Normo (N, L), kEDM Table 1
 F1_L = 29484  # F1 series length, kEDM Table 1
 F1_N = 256  # cut from the published 8520 so phase b ends in about a minute
 F1_E = 10  # fixed embedding dimension of phase b
+APPENDS = 4  # phase c: appends of 8 samples within the panel's capacity
 
 
 class CompileClock:
@@ -87,11 +90,13 @@ class CompileClock:
     def __init__(self):
         import jax
         self.s = 0.0
+        self.n = 0
         jax.monitoring.register_event_duration_secs_listener(self._on)
 
     def _on(self, event, duration, **_):
         if event == self.EVENT:
             self.s += duration
+            self.n += 1
 
 
 def run_phase(clock, name, shape, impl, fn):
@@ -237,22 +242,35 @@ def phase_b(clock, panel, E, impl_want):
 
 
 def phase_c(clock, grown, L, E_opt, impl_want):
-    """EDMServer: ccm requests, one append, ccm again — all bit-matched."""
+    """EDMServer: ccm requests, APPENDS appends with ccm between them —
+    no compile once the first append has sized the capacity, and every
+    answer bit-matches a direct session at the same capacity."""
     from repro.edm import EDM
     from repro.serving import EDMServer
 
-    panel, delta = grown[:, :L], grown[:, L:]
-    N = panel.shape[0]
+    N = grown.shape[0]
+    dt = (grown.shape[1] - L) // APPENDS
     pairs = [(int(l), int(t)) for l, t in
              zip(sample_rows(N, 6), sample_rows(N, 6)[::-1])]
 
     def serve(srv):
         futs = [srv.submit("ccm", "fish", lib=l, target=t,
                            E=int(E_opt[t])) for l, t in pairs]
+        while srv.scheduler.drain_once():
+            pass
         return [np.float32(f.result(timeout=600)) for f in futs]
 
+    def append(srv, n):
+        fut = srv.submit("append", "fish",
+                         delta=grown[:, L + (n - 1) * dt:L + n * dt])
+        srv.scheduler.drain_once()
+        info = fut.result(timeout=600)
+        require(info["L"] == L + n * dt, f"append info {info}")
+
     def oracle(data):
-        sess = EDM(data)
+        sess = EDM(data[:, :L])
+        if data.shape[1] > L:  # appended before the master: built at C
+            sess.append(data[:, L:])
         require(sess.plan("ccm").impl == impl_want, "oracle impl")
         return [sess.ccm_batch([p], E=int(E_opt[p[1]]))[0] for p in pairs]
 
@@ -262,19 +280,28 @@ def phase_c(clock, grown, L, E_opt, impl_want):
                     f"{what} ccm{p}: served {g!r} != direct {w!r}")
 
     def c1():
-        srv = EDMServer()
+        srv = EDMServer(autostart=False)
         try:
-            srv.register_panel("fish", panel)
+            srv.register_panel("fish", grown[:, :L])
+            srv.submit("optimal_E", "fish")  # the master at every level
+            srv.scheduler.drain_once()
             before = serve(srv)
-            info = srv.call("append", "fish", delta=delta, timeout=600)
-            require(info["L"] == grown.shape[1], f"append info {info}")
-            after = serve(srv)
+            append(srv, 1)  # sizes C, compiles the (C, dt) append program
+            serve(srv)  # and the (C, E, batch) ccm programs
+            capacity = srv.registry.get("fish").sess.data.capacity
+            n0 = clock.n
+            for n in range(2, APPENDS + 1):
+                append(srv, n)
+                after = serve(srv)
+            compiles = clock.n - n0
         finally:
             srv.close()
-        bit_equal(before, oracle(panel), "pre-append")
+        require(compiles == 0, f"{compiles} compiles after the first append")
+        bit_equal(before, oracle(grown[:, :L]), "pre-append")
         bit_equal(after, oracle(grown), "post-append")
-        return (f"{len(pairs)} ccm + append(dt={delta.shape[1]}) + "
-                f"{len(pairs)} ccm: bit-equal to direct sessions")
+        return (f"capacity {capacity}: {APPENDS} appends of {dt} with "
+                f"{len(pairs)} ccm between, 0 compiles after the first; "
+                f"bit-equal to direct sessions")
 
     run_phase(clock, "c1 EDMServer ccm/append/ccm", grown.shape,
               impl_want, c1)
@@ -354,7 +381,7 @@ def main() -> int:
         panel = forced_network_panel(N, L, seed=args.seed)[0]
         four_chip_phases(clock, panel, 4, "pallas")
     else:
-        grown = forced_network_panel(N, L + 8, seed=args.seed)[0]
+        grown = forced_network_panel(N, L + 8 * APPENDS, seed=args.seed)[0]
         E_opt = phase_a(clock, grown[:, :L], "pallas")
         f1 = forced_network_panel(F1_N, F1_L, seed=args.seed)[0]
         phase_b(clock, f1, F1_E, "pallas")

@@ -12,8 +12,9 @@ Part 1, the single-panel smoke:
   answer contract: batching and transport never change bits
   (``EDM.ccm_batch`` on a singleton pair is the quiesced CCM oracle);
 * submit one **append tick** through the server and assert post-append
-  answers bit-match a COLD session built on the grown panel — the
-  incremental kNN-master merge is indistinguishable from a rebuild;
+  answers bit-match a cold session grown by the same append (whose
+  master is built at the capacity that append sizes) — the incremental
+  kNN-master merge is indistinguishable from a rebuild;
 * record the whole run to a telemetry JSONL sink and assert it is
   schema-valid and contains the serve spans/metrics CI expects.
 
@@ -100,10 +101,12 @@ def main() -> None:
     rng = np.random.default_rng(5)
     delta = rng.standard_normal((panel.shape[0], 6)).astype(np.float32)
 
-    # Direct oracles: the same answers with no server in the loop.
+    # Direct oracles: the same answers with no server in the loop. The
+    # grown one takes the same append, which sizes its capacity as the
+    # server's panel's (its master is then built cold at that capacity).
     direct = EDM(panel, EDMConfig(**CFG))
-    direct_grown = EDM(np.concatenate([panel, delta], axis=1),
-                       EDMConfig(**CFG))
+    direct_grown = EDM(panel, EDMConfig(**CFG))
+    direct_grown.append(delta)
     pairs = [(i, (i + 1) % panel.shape[0]) for i in range(panel.shape[0])]
     oracle = {p: direct.ccm_batch([p], E=E_REQ)[0] for p in pairs}
     oracle_grown = {p: direct_grown.ccm_batch([p], E=E_REQ)[0] for p in pairs}
@@ -150,7 +153,7 @@ def main() -> None:
                               np.asarray(direct.xmap(), np.float32),
                               equal_nan=True)
 
-        # --- one append tick: server == COLD session on the grown panel
+        # --- one append tick: server == cold session grown by the same tick
         info = _post(port, "append", panel="smoke",
                      delta=delta.tolist())["result"]
         assert info["L"] == panel.shape[1] + delta.shape[1], info
@@ -206,7 +209,10 @@ def soak() -> None:
     for name, x in full.items():
         per_v = []
         for v in range(SOAK_TICKS + 1):
-            sess = EDM(x[:, : SOAK_L + v * SOAK_DT], EDMConfig(**CFG))
+            sess = EDM(x[:, :SOAK_L], EDMConfig(**CFG))
+            for t in range(v):  # the server's ticks, in turn
+                sess.append(x[:, SOAK_L + t * SOAK_DT:
+                              SOAK_L + (t + 1) * SOAK_DT])
             per_v.append({p: sess.ccm_batch([p], E=E_REQ)[0]
                           for p in pairs})
             one_master = max(one_master, sess.master_nbytes())
@@ -338,8 +344,10 @@ def durability_smoke() -> None:
         return p, int(line.split()[1])
 
     def oracle_at(k: int):
-        return EDM(np.concatenate([panel] + deltas[:k], axis=1),
-                   EDMConfig(**CFG))
+        sess = EDM(panel, EDMConfig(**CFG))
+        for d in deltas[:k]:  # the server's appends, in turn
+            sess.append(d)
+        return sess
 
     p1, port = spawn()
     try:

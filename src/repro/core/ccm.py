@@ -254,7 +254,7 @@ def auto_batch_libs(Lp: int, Nl: int, budget_mb: float | None = None, *,
     return -(-Nl // nb)
 
 
-def post_lookup_rho(targets, d, i, *, rows, off, impl):
+def post_lookup_rho(targets, d, i, *, rows, off, impl, live_rows=None):
     """Per-series weights + fused-ρ stage of every batched matrix engine.
 
     (d, i) are (B, Lp, k) neighbor tables; returns (B, Nt) ρ via a
@@ -265,13 +265,16 @@ def post_lookup_rho(targets, d, i, *, rows, off, impl):
     (``edm.plan._master_group_step``), and the per-shard engine
     (``distributed.sharded_ccm._local_block``) all share this one
     implementation instead of keeping three copies in sync.
+    ``live_rows`` (an operand) correlates only the first ``live_rows``
+    of the ``rows`` table rows: the capacity-panel engine's tables span
+    the capacity, its valid prefix is shorter.
     """
 
     def post(args):
         dB, iB = args
         w = ops.make_weights(dB)
-        return ops.lookup_rho(targets, iB[:rows], w[:rows], offset=off,
-                              impl=impl)
+        return ops.lookup_rho(targets, iB[:rows], w[:rows], live_rows,
+                              offset=off, impl=impl)
 
     return jax.lax.map(post, (d, i))
 

@@ -116,6 +116,24 @@ def screen_panel(panel: np.ndarray, *, prior: dict | None = None
     return _records(m["cnt"], m["lo"], m["hi"], delta_cnt=stats["cnt"])
 
 
+def grown_capacity(L: int) -> int:
+    """The capacity a live panel regrows to at length L: 1.25·L rounded
+    up to a multiple of 128. Each regrow recompiles every serving
+    program, so the room grows with the recording (regrows are
+    logarithmic in its length), and the masked rows past L cost at most
+    a quarter more work; 128, the vector lane width, keeps the row
+    blocks of the capacity programs whole."""
+    return -(-(-(-5 * int(L) // 4)) // 128) * 128
+
+
+@jax.jit
+def _write_columns(buf, delta, at):
+    """``buf`` with ``delta`` written at column ``at`` (an operand): one
+    program per (N, capacity, dt), whatever the valid length."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        buf, delta.astype(buf.dtype), at, axis=1)
+
+
 class Dataset:
     """An (N, L) panel of equal-length series with embedding caches.
 
@@ -124,6 +142,16 @@ class Dataset:
     matrices and user inspection — the distance kernels fuse theirs) are
     computed once per (E, tau) and held here. ``on_invalid`` sets the
     NaN/Inf/constant-series policy (module docstring).
+
+    **Capacity.** The series are stored in an (N, C) ``buffer`` whose
+    first L columns are valid; ``panel`` is always the exact (N, L)
+    view. A panel starts exact (C = L), so one that never appends keeps
+    the shapes and the work it always had. An append that would pass C
+    — the first append of a live panel, then each one that outgrows the
+    room — regrows the buffer to ``grown_capacity(L_new)``, and every
+    append within C writes into it and keeps every shape: the append and
+    ``ccm_batch`` programs, which read L as an operand, compile nothing
+    until the next regrow.
     """
 
     def __init__(self, panel, *, names=None, on_invalid: str = "raise"):
@@ -171,11 +199,18 @@ class Dataset:
         elif report:  # mask: zero non-finite entries so kernels/top-k
             panel = jnp.nan_to_num(  # never see NaN; outputs touching
                 panel, nan=0.0, posinf=0.0, neginf=0.0)  # them are NaN'd
-        self.panel = panel
         self.names = names
         self.valid = valid
         self._stats = stats  # running series_stats of the raw panel
         self._embeddings: dict[tuple[int, int], jax.Array] = {}
+        self._bind(panel, int(panel.shape[1]), int(panel.shape[1]))
+
+    def _bind(self, panel, L: int, C: int) -> None:
+        """Hold the exact (N, L) ``panel`` in an (N, C) buffer."""
+        self.buffer = panel if C == L else jnp.pad(panel,
+                                                   ((0, 0), (0, C - L)))
+        self._L = L
+        self._view = panel
 
     def append(self, delta) -> list[dict]:
         """Grow every series by Δt points under the bound policy.
@@ -219,7 +254,7 @@ class Dataset:
         merged = merge_stats(self._stats, series_stats(arr))
         if self.num_invalid or fresh:  # mask policy: keep NaN out of kernels
             delta = jnp.nan_to_num(delta, nan=0.0, posinf=0.0, neginf=0.0)
-        panel = jnp.concatenate([self.panel, delta], axis=1)
+        keep = None
         if fresh and self.on_invalid == "drop":
             bad = {r["index"] for r in fresh}
             keep = np.array([i for i in range(self.N) if i not in bad], int)
@@ -227,27 +262,44 @@ class Dataset:
                 raise ValueError(
                     "append would invalidate every remaining series; "
                     "refusing to drop the whole panel")
-            panel = panel[keep]
+        L, dt = self._L, int(delta.shape[1])
+        if L + dt > self.capacity:
+            self._bind(self.panel, L, grown_capacity(L + dt))
+        self.buffer = _write_columns(self.buffer, delta, np.int32(L))
+        self._L, self._view = L + dt, None
+        if keep is not None:
+            self.buffer = self.buffer[keep]
             merged = {k: v[keep] for k, v in merged.items()}
             if self.names is not None:
                 self.names = [self.names[i] for i in keep]
-            self.valid = np.ones(panel.shape[0], bool)
+            self.valid = np.ones(len(keep), bool)
         else:
             self.valid = np.asarray(
                 (merged["cnt"] == 0) & (merged["lo"] < merged["hi"]))
-        self.panel = panel
         self._stats = merged
         self.invalid_report = self.invalid_report + fresh
         self._embeddings.clear()
         return fresh
 
     @property
+    def panel(self) -> jax.Array:
+        """The exact (N, L) panel: the buffer's valid columns."""
+        if self._view is None:
+            self._view = self.buffer[:, :self._L]
+        return self._view
+
+    @property
+    def capacity(self) -> int:
+        """Columns the buffer holds (C ≥ L); see the class docstring."""
+        return int(self.buffer.shape[1])
+
+    @property
     def N(self) -> int:
-        return self.panel.shape[0]
+        return self.buffer.shape[0]
 
     @property
     def L(self) -> int:
-        return self.panel.shape[1]
+        return self._L
 
     @property
     def num_invalid(self) -> int:
@@ -281,4 +333,6 @@ class Dataset:
 
     def __repr__(self) -> str:  # pragma: no cover - cosmetic
         bad = f", invalid={self.num_invalid}" if self.num_invalid else ""
-        return f"Dataset(N={self.N}, L={self.L}{bad})"
+        cap = (f", capacity={self.capacity}" if self.capacity != self.L
+               else "")
+        return f"Dataset(N={self.N}, L={self.L}{cap}{bad})"

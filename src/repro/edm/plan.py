@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from repro import telemetry
 from repro.core.embedding import embed_offset, num_embedded, pred_rows
 from repro.kernels import ops
-from repro.kernels.ref import strict_sq
+from repro.kernels import ref
+from repro.kernels.ref import PAD_IDX, strict_sq
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,27 +86,76 @@ def panel_master(X, *, E_max, tau, k, impl):
     return jax.lax.map(one, X)
 
 
-@functools.partial(jax.jit, static_argnames=("tau", "impl"))
+def pad_master(dM, iM, capacity: int):
+    """Master tables padded along their row axis to ``capacity`` rows.
+
+    A capacity master holds each series' exact tables in its first L
+    rows and inf / ``PAD_IDX`` below — what the tables hold past a
+    level's valid rows anyway, so every consumer that slices the rows it
+    needs (``[:Lp]``, ``[:rows]``) reads the same bits as from the exact
+    tables.
+    """
+    extra = int(capacity) - int(dM.shape[2])
+    if extra == 0:
+        return dM, iM
+    pad = ((0, 0), (0, 0), (0, extra), (0, 0))
+    return (jnp.pad(dM, pad, constant_values=jnp.inf),
+            jnp.pad(iM, pad, constant_values=PAD_IDX))
+
+
+@functools.partial(jax.jit, static_argnames=("tau",))
+def panel_master_state(X, dM, iM, *, tau):
+    """A panel master's append state: (sq, idx), each (E_max, k, N, C).
+
+    ``ref.append_state`` per series (sequential ``lax.map``, as in
+    ``panel_master``): the stored candidates' squared distances and
+    indices, the slots leading and the rows minor — the physical order
+    of the master tables themselves, so the tables a tick returns are
+    the state's transposes without a copy. Recomputing the squares
+    gathers every stored candidate's lag terms, so a session computes
+    the state once per master and the appends carry it forward
+    (``panel_master_append_sq``).
+    """
+    sq, idx = jax.lax.map(lambda a: ref.append_state(*a, tau=tau),
+                          (X, dM, iM))
+    return jnp.moveaxis(sq, 0, 2), jnp.moveaxis(idx, 0, 2)
+
+
+@functools.partial(jax.jit, static_argnames=("dt", "tau", "impl"))
+def panel_master_append_sq(X, sq, idx, length, *, dt, tau, impl):
+    """One append tick of a whole capacity panel → (sq, idx, dM, iM).
+
+    ``X`` is the (N, C) panel buffer holding the grown series in
+    [0, length + dt); ``sq``/``idx`` the master's append state, valid
+    for ``length`` points (an operand): one program per
+    (N, C, dt, E_max) serves every tick within the capacity. Returns
+    the grown state and the master tables it gives, (N, E_max, C, k) —
+    bit-identical to ``panel_master`` on the grown panel padded to C
+    rows, at O(Lp·(k+Δt)) per level instead of O(Lp²). k_master is
+    preserved, so the ``master_slack_covers`` slack rule carries over
+    unchanged.
+    """
+    sq, idx = ops.master_append_sq(X, sq, idx, length=length, dt=dt,
+                                   tau=tau, impl=impl)
+    return (sq, idx, jnp.sqrt(jnp.maximum(sq, 0.0)).transpose(2, 0, 3, 1),
+            idx.transpose(2, 0, 3, 1))
+
+
 def panel_master_append(X, dM, iM, *, tau, impl):
     """Grow a whole panel's master tables to cover appended points.
 
     ``X`` is the grown (N, L_new) panel; ``dM``/``iM`` the stored
-    ``panel_master`` tables of its (N, L_old) prefix. One
-    ``ops.master_append`` merge per series (sequential ``lax.map``, as
-    in ``panel_master``) → (N, E_max, L_new, k) tables bit-identical to
-    ``panel_master`` on the grown panel, at O(Lp·(k+Δt)) per level
-    instead of O(Lp²). The serving path's per-tick master update
-    (``EDM.append``); k_master is preserved, so the
-    ``master_slack_covers`` slack rule carries over unchanged.
+    ``panel_master`` tables of its (N, L_old) prefix. Computes the
+    append state (``panel_master_state``) and runs one tick → (N, E_max,
+    L_new, k) tables bit-identical to ``panel_master`` on the grown
+    panel. A serving session keeps the state between ticks instead
+    (``EDM.append``).
     """
-
-    def one(args):
-        x, d, i = args
-        return ops.master_append(x, d, i, tau=tau, impl=impl)
-
-    return jax.lax.map(one, (X, dM, iM))
-
-
+    L_old = int(dM.shape[2])
+    dM, iM = pad_master(dM, iM, X.shape[-1])
+    sq, idx = panel_master_state(X, dM, iM, tau=tau)
+    return panel_master_append_sq(X, sq, idx, L_old, dt=X.shape[-1] - L_old,
+                                  tau=tau, impl=impl)[2:]
 
 
 def _derive_idx(iE, *, k, max_idx):
@@ -280,7 +330,8 @@ def _gathered_dists_batch(X, idx, ok, *, E, tau):
 
 
 @functools.partial(jax.jit, static_argnames=("E", "tau", "Tp", "k", "impl"))
-def _master_group_step(Xb, iMb, targets, *, E, tau, Tp, k, impl):
+def _master_group_step(Xb, iMb, targets, length=None, *, E, tau, Tp, k,
+                       impl):
     """One master-derived engine launch: (B, Nt) ρ for B libraries.
 
     The cached-session twin of ``core.ccm._group_step``: neighbor
@@ -288,6 +339,13 @@ def _master_group_step(Xb, iMb, targets, *, E, tau, Tp, k, impl):
     (zero kNN work), the k selected distances are recomputed in pairwise
     accumulation order, and weights + fused-ρ lookups run as per-series
     ``lax.map`` sub-steps (per-series shapes ⇒ bit-invariant in B).
+
+    ``length`` (an operand) is the valid length of a capacity panel,
+    whose series and master span C ≥ L: neighbour indices are capped at
+    Lp − 1 − Tp of the valid length and the Pearson sums run over its
+    first Lp − Tp rows only, so one program per (C, E, B) serves every
+    L ≤ C. The masked sums round in another order than an exact-shape
+    program's, so the two agree to float32 rounding.
     """
     from repro.core.ccm import post_lookup_rho
 
@@ -295,10 +353,17 @@ def _master_group_step(Xb, iMb, targets, *, E, tau, Tp, k, impl):
     Lp = num_embedded(L, E, tau)
     rows = pred_rows(L, E, tau, Tp)
     off = embed_offset(E, tau, Tp)
-    hard_max = Lp - 1 - max(Tp, 0)
+    live = None
+    if length is None:
+        hard_max = Lp - 1 - max(Tp, 0)
+    else:
+        Lp_live = length - (E - 1) * tau
+        hard_max = Lp_live - 1 - max(Tp, 0)
+        live = Lp_live - max(Tp, 0)
     ik, ok = _derive_idx(iMb[:, :Lp], k=k, max_idx=hard_max)
     d = _gathered_dists_batch(Xb, ik, ok, E=E, tau=tau)
-    return post_lookup_rho(targets, d, ik, rows=rows, off=off, impl=impl)
+    return post_lookup_rho(targets, d, ik, rows=rows, off=off, impl=impl,
+                           live_rows=live)
 
 
 def make_master_group_launch(X, iM_E, targets, *, E, tau, Tp, k, impl):
@@ -334,40 +399,55 @@ def master_group_batch_bytes(Lp: int, k_master: int) -> int:
     return 16 * Lp * int(k_master)
 
 
-def ccm_group_from_master_batched(X, iM_E, targets, *, E, tau, Tp, k, impl,
-                                  batch_libs=None,
-                                  budget_mb=None) -> "np.ndarray":
-    """Library-batched CCM block from cached master indices → (N, Nt) ρ.
+@functools.partial(jax.jit, static_argnames=("E", "tau", "Tp", "k", "impl"))
+def _ccm_batch_step(P, iM, libs, length, *, E, tau, Tp, k, impl):
+    """One ``EDM.ccm_batch`` launch: (B, N) ρ for the libraries ``libs``.
 
-    The cached-session counterpart of ``core.ccm.ccm_group_batched``:
-    ceil(N/B) double-buffered ``_master_group_step`` launches instead of
-    N sequential ``lax.map`` steps. B is sized against this engine's
-    *actual* in-flight footprint — O(B·Lp·k_master) for the batched
-    stable-filter sort plus gathered-distance stage, NOT the direct
-    engine's (B, Lp, Lp) distance stack (which derivation never holds):
-    sizing by the distance-stack rule would collapse B to 1 on long
-    series exactly where batching the derivation is cheapest.
+    ``P`` is the (N, C) panel buffer, ``iM`` the (N, E_levels, C,
+    k_master) master, ``length`` the valid L: the libraries' rows are
+    gathered inside the program, which is ``_master_group_step`` against
+    every series, one program per (C, E, B).
+    """
+    Lp = num_embedded(P.shape[-1], E, tau)  # gather the rows it reads
+    return _master_group_step(P[libs], iM[libs, E - 1, :Lp], P, length,
+                              E=E, tau=tau, Tp=Tp, k=k, impl=impl)
+
+
+def ccm_batch_from_master(P, iM, libs, length, *, E, tau, Tp, k, impl,
+                          budget_mb=None) -> "np.ndarray":
+    """(len(libs), N) ρ of the libraries ``libs`` against every series.
+
+    The ``EDM.ccm_batch`` engine on a capacity panel: ``P`` (N, C), the
+    master ``iM`` at the same capacity, ``length`` the valid L. B is
+    sized against the engine's in-flight footprint
+    (``master_group_batch_bytes``; a coalesced batch is normally one
+    launch of B = len(libs)); each launch is one ``_ccm_batch_step``
+    through ``drive_batched``, so the launch and dispatch counters and
+    spans are those of every engine.
     """
     from repro.core.ccm import auto_batch_libs, drive_batched
 
     import numpy as np
 
-    X = jnp.asarray(X)
-    iM_E = jnp.asarray(iM_E)
-    Nl = X.shape[0]
-    Lp = num_embedded(X.shape[-1], E, tau)
-    if Nl == 0:  # empty library axis: empty matrix, like the legacy path
-        return np.zeros((0, targets.shape[0]), np.float32)
-    if batch_libs is not None:
-        B = batch_libs
-    else:
-        B = auto_batch_libs(
-            Lp, Nl, budget_mb,
-            per_series_bytes=master_group_batch_bytes(Lp, iM_E.shape[-1]))
-    B = max(1, min(int(B), max(Nl, 1)))
+    libs = np.asarray(libs, np.int32)
+    Nl = len(libs)
+    Lp = num_embedded(P.shape[-1], E, tau)
+    B = auto_batch_libs(Lp, Nl, budget_mb,
+                        per_series_bytes=master_group_batch_bytes(
+                            Lp, iM.shape[-1]))
+    B = max(1, min(int(B), Nl))
     telemetry.gauge("edm_batch_libs_effective").set(B)
-    launch = make_master_group_launch(X, iM_E, targets, E=E, tau=tau, Tp=Tp,
-                                      k=k, impl=impl)
+    impl_r = ops.resolve_impl(impl)
+    master_launches = telemetry.counter("edm_master_launches")
+    L = np.int32(length)
+
+    def launch(a, b, B):
+        master_launches.inc()
+        part = libs[a:b]
+        part = np.concatenate([part, np.repeat(part[-1:], B - len(part))])
+        return _ccm_batch_step(P, iM, part, L, E=E, tau=tau, Tp=Tp, k=k,
+                               impl=impl_r)
+
     return drive_batched(Nl, B, launch)
 
 
@@ -380,8 +460,8 @@ def ccm_group_from_master(X, iM_E, targets, *, E, tau, Tp, k, impl):
     neighbors are derived from its master index level (iM_E, (N, L,
     k_master)) and only the k selected distances are recomputed —
     bit-identical output (see module docstring). Kept as the legacy
-    per-series reference; the session dispatches
-    ``ccm_group_from_master_batched``.
+    per-series reference; the session dispatches the batched engine
+    (``make_master_group_launch``, ``ccm_batch_from_master``).
     """
     L = X.shape[-1]
     Lp = num_embedded(L, E, tau)

@@ -40,11 +40,13 @@ from repro.edm.config import EDMConfig
 from repro.edm.dataset import Dataset
 from repro.edm.plan import (
     Plan,
+    ccm_batch_from_master,
     ccm_convergence_from_master,
-    ccm_group_from_master_batched,
     master_slack_covers,
+    pad_master,
     panel_master,
-    panel_master_append,
+    panel_master_append_sq,
+    panel_master_state,
     rho_curves_from_master,
     simplex_skill_from_master,
 )
@@ -92,6 +94,17 @@ class EDM:
     """Session facade: shared kNN/embedding state + plan-based dispatch."""
 
     def __init__(self, data, config: EDMConfig | None = None, **overrides):
+        """Bind ``data`` (a ``Dataset`` or an (N, L) array) under
+        ``config`` (or ``EDMConfig(**overrides)``).
+
+        The panel and its kNN master are held at the dataset's capacity
+        C ≥ L, with L an operand of the append and ``ccm_batch``
+        programs. A panel starts exact (C = L); its first append sizes C
+        with room to grow (``dataset.grown_capacity``), so later appends
+        and ``ccm_batch`` compile nothing until L passes C, when the
+        panel regrows — one recompile of each program, counted in
+        ``edm_capacity_regrows``.
+        """
         if config is None:
             config = EDMConfig(**overrides)
         elif overrides:
@@ -261,10 +274,12 @@ class EDM:
             self._bump("knn_master_hits")
             return hit
         k_m = max(E_levels + 1, c.k or 0) + c.slack
+        self._cache.pop("append_state", None)  # the old master's
         with telemetry.span("session.master_build", E_levels=E_levels,
                             k_master=k_m, N=self.data.N):
-            dM, iM = panel_master(self.data.panel, E_max=E_levels,
-                                  tau=c.tau, k=k_m, impl=self._impl)
+            dM, iM = pad_master(*panel_master(
+                self.data.panel, E_max=E_levels, tau=c.tau, k=k_m,
+                impl=self._impl), self.data.capacity)
         self._bump("knn_master_builds")
         hit = self._cache["master"] = (dM, iM, k_m, E_levels)
         return hit
@@ -273,14 +288,15 @@ class EDM:
         """Resident bytes of the cached multi-E kNN master (0 if none).
 
         The serving LRU's accounting unit: the master is the session's
-        only O(N·E·Lp·k) cache (distances + indices), everything else
-        held here is O(N·E_max) or smaller.
+        only O(N·E·Lp·k) cache (distances + indices, and on a panel that
+        appends the append state the ticks carry), everything else held
+        here is O(N·E_max) or smaller.
         """
         hit = self._cache.get("master")
         if hit is None:
             return 0
-        dM, iM = hit[0], hit[1]
-        return int(dM.nbytes) + int(iM.nbytes)
+        state = self._cache.get("append_state", ())
+        return sum(int(a.nbytes) for a in (*hit[:2], *state))
 
     def evict_master(self) -> int:
         """Drop the cached kNN master; returns the bytes freed.
@@ -295,6 +311,7 @@ class EDM:
         freed = self.master_nbytes()
         if freed:
             self._cache.pop("master", None)
+            self._cache.pop("append_state", None)
             self._bump("knn_master_evictions")
         return freed
 
@@ -303,33 +320,58 @@ class EDM:
 
         The serving tick primitive: screening covers only the new
         columns (``Dataset.append``), and a cached kNN master is grown
-        by ``panel_master_append`` — O(Lp·Δt) stream-in/merge per
+        by ``panel_master_append_sq`` — O(Lp·Δt) stream-in/merge per
         series, bit-identical to the cold O(Lp²) rebuild — so a warm
-        session absorbs a tick without repaying its build. Derived
-        caches that summarize the whole panel (the optimal-E rho
-        curves) are invalidated; the master survives. Under
-        ``on_invalid="drop"`` the master rows of dropped series are
-        compacted to match the panel. Returns ``Dataset.append``'s
-        records of series this delta invalidated (pre-append indices).
+        session absorbs a tick without repaying its build. The merge
+        runs on the master's append state (its stored candidates'
+        squared distances and indices), which the first append after a
+        build or a regrow computes once (``panel_master_state``) and
+        every append then carries forward. Within the panel's capacity
+        the tick writes into the held buffers and compiles nothing; an
+        append that passes it regrows the panel and master (one
+        recompile, counted in ``edm_capacity_regrows`` and logged as a
+        ``session.capacity_regrow`` event) — which the first append of
+        a panel always does, since a panel starts exact. Derived caches
+        that summarize the whole panel (the optimal-E rho curves) are
+        invalidated; the master survives. Under ``on_invalid="drop"``
+        the master rows of dropped series are compacted to match the
+        panel. Returns ``Dataset.append``'s records of series this delta
+        invalidated (pre-append indices).
         """
         c = self.config
-        old_N = self.data.N
+        old_N, old_L = self.data.N, self.data.L
+        old_C = self.data.capacity
         with telemetry.span("session.append", N=old_N):
             records = self.data.append(delta)  # raises before mutating
+            C = self.data.capacity
+            if C != old_C:
+                self._bump("capacity_regrows")
+                telemetry.event("session.capacity_regrow", capacity_was=old_C,
+                                capacity=C, L=self.data.L)
             self._cache.pop("rho", None)
             hit = self._cache.get("master")
+            state = self._cache.pop("append_state", None)
             if hit is not None and c.cache:
                 dM, iM, k_m, lv = hit
                 if len(records) and self.data.N != old_N:  # drop compaction
                     keep = np.setdiff1d(
                         np.arange(old_N), [r["index"] for r in records])
-                    dM, iM = dM[keep], iM[keep]
-                dt = int(self.data.L) - int(dM.shape[2])
+                    dM, iM, state = dM[keep], iM[keep], None
+                if C != old_C:
+                    dM, iM, state = *pad_master(dM, iM, C), None
+                if state is None:  # once per master and capacity
+                    with telemetry.span("session.append_state",
+                                        N=self.data.N):
+                        state = panel_master_state(self.data.buffer, dM, iM,
+                                                   tau=c.tau)
+                dt = self.data.L - old_L
                 with telemetry.span("session.master_append", dt=dt,
-                                    E_levels=lv, N=self.data.N):
-                    dM, iM = panel_master_append(
-                        self.data.panel, dM, iM, tau=c.tau, impl=self._impl)
+                                    E_levels=lv, N=self.data.N, capacity=C):
+                    *state, dM, iM = panel_master_append_sq(
+                        self.data.buffer, *state, np.int32(old_L), dt=dt,
+                        tau=c.tau, impl=self._impl)
                 self._cache["master"] = (dM, iM, k_m, lv)
+                self._cache["append_state"] = tuple(state)
                 self._bump("knn_master_appends")
             else:
                 self._cache.pop("master", None)
@@ -556,9 +598,13 @@ class EDM:
 
         The serving primitive: n compatible requests (same panel, same
         E) become ONE library-batched engine launch
-        (``ccm_group_from_master_batched`` — the xmap matrix engine)
-        instead of n single-pair passes, ~20× the pairs/s on saturated
-        queues. Its bit contract is *batch invariance*: the launch
+        (``plan.ccm_batch_from_master``, the master-derived matrix
+        engine) instead of n single-pair passes, ~20× the pairs/s on
+        saturated queues. The launch takes the panel buffer, the master
+        and the library indices, gathers inside the program and reads
+        the valid length as an operand: one program per (capacity, E,
+        libraries per batch), which appends within the capacity leave
+        warm. Its bit contract is *batch invariance*: the launch
         always cross-maps against the full panel's target set and the
         library axis is batch-invariant, so a pair's ρ is a pure
         function of (library state, lib, target, E) — the same bits no
@@ -593,10 +639,9 @@ class EDM:
             libs = sorted({li for _, li, _ in live})
             sp.annotate(libs=len(libs))
             lpos = {li: i for i, li in enumerate(libs)}
-            la = jnp.asarray(libs)
-            g = np.asarray(ccm_group_from_master_batched(
-                self.data.panel[la], hit[1][la, E - 1], self.data.panel,
-                E=E, tau=c.tau, Tp=c.Tp_cross, k=k, impl=self._impl))
+            g = ccm_batch_from_master(
+                self.data.buffer, hit[1], libs, self.data.L, E=E,
+                tau=c.tau, Tp=c.Tp_cross, k=k, impl=self._impl)
             for j, li, ti in live:
                 out[j] = g[lpos[li], ti]
         self._bump("ccm_batch_pairs", len(live))

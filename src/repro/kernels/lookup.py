@@ -137,7 +137,12 @@ def lookup(
 # ---------------------------------------------------------------- fused rho
 
 
-def _kernel_rho(yT_ref, i_ref, w_ref, yt_ref, s_ref, yh_ref, *, k, bj, Lp):
+def _kernel_rho(yT_ref, i_ref, w_ref, yt_ref, *refs, k, bj, Lp, masked):
+    if masked:  # valid row count read from SMEM: one program, any length
+        n_ref, s_ref, yh_ref = refs
+        Lp = jnp.minimum(n_ref[0], Lp)
+    else:
+        s_ref, yh_ref = refs
     j = pl.program_id(1)
     j0 = j * bj
 
@@ -191,6 +196,7 @@ def lookup_rho(
     Y: jax.Array,
     idx: jax.Array,
     w: jax.Array,
+    rows=None,
     *,
     offset: int = 0,
     block: tuple[int, int] = (128, 128),
@@ -199,6 +205,10 @@ def lookup_rho(
     """Fused lookup + Pearson ρ per target. Returns (N,) float32.
 
     The (N, Lp) prediction matrix never leaves VMEM (paper §3.4).
+    ``rows`` (an operand, may be traced) limits the correlation to the
+    first ``rows`` table rows, as the ragged edge is masked: the kernel
+    reads it from SMEM, so a capacity panel's one program serves every
+    valid length below its capacity.
     """
     N, L = Y.shape
     Lp, k = idx.shape
@@ -207,18 +217,23 @@ def lookup_rho(
     # Aligned truth rows: row j of the tile is Y[:, j0 + j + offset].
     ytrue = jnp.pad(jax.lax.slice_in_dim(yT, offset, offset + Lp, axis=0),
                     ((0, gj * bj - Lp), (0, 0)))
+    masked = rows is not None
+    in_specs = _target_specs(L, bn, bj, k) + [
+        pl.BlockSpec((bj, bn), lambda n, j: (j, n))]
+    args = [yT, it, wt, ytrue]
+    if masked:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(jnp.reshape(rows, (1,)).astype(jnp.int32))
     stats = pl.pallas_call(
-        functools.partial(_kernel_rho, k=k, bj=bj, Lp=Lp),
+        functools.partial(_kernel_rho, k=k, bj=bj, Lp=Lp, masked=masked),
         grid=(gn, gj),  # j innermost: stats block revisited across j
-        in_specs=_target_specs(L, bn, bj, k) + [
-            pl.BlockSpec((bj, bn), lambda n, j: (j, n)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((8, bn), lambda n, j: (0, n)),
         out_shape=jax.ShapeDtypeStruct((8, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bj, bn), jnp.float32)],
         compiler_params=_vmem_params(L, bn, bj),
         interpret=interpret,
-    )(yT, it, wt, ytrue)
+    )(*args)
     M2a, M2b, C = stats[3], stats[4], stats[5]
     denom = jnp.sqrt(M2a * M2b)
     return jnp.where(denom > 0, C / jnp.maximum(denom, 1e-30), 0.0)

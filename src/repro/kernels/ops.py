@@ -292,18 +292,56 @@ def master_append(
     ``all_knn_multi_e`` tables of its prefix (uniform k — masters).
     Returns the grown (E_max, L_new, k_m) tables, bit-identical to a
     cold rebuild on ``x`` (see the append section in kernels/ref.py for
-    the strict-chain rules that make that hold). The impl knob selects
-    the merge-stage engine — ref's ``top_k`` and the Pallas k-best merge
+    the strict-chain rules that make that hold): the stored candidates'
+    squared distances are recomputed (``ref.append_state``) and one
+    ``master_append_sq`` tick runs on them. The impl knob selects the
+    merge-stage engine — ref's ``top_k`` and the kernel path
     (kernels/knn_append.py) are bit-identical selection over the same
     candidate bits.
     """
+    dt = _ref.check_append_args(x, dists, idx, tau)
+    L_old = int(dists.shape[1])
+    pad = ((0, 0), (0, dt), (0, 0))  # the grown tables' rows, as inf/PAD
+    sq, it = _ref.append_state(
+        x, jnp.pad(dists, pad, constant_values=jnp.inf),
+        jnp.pad(idx, pad, constant_values=_ref.PAD_IDX), tau=tau)
+    sq, it = master_append_sq(x[None], sq[:, :, None], it[:, :, None],
+                              length=L_old, dt=dt, tau=tau, impl=impl,
+                              block=block)
+    return (jnp.sqrt(jnp.maximum(sq[:, :, 0], 0.0)).swapaxes(1, 2),
+            it[:, :, 0].swapaxes(1, 2))
+
+
+def master_append_sq(
+    X: jax.Array,
+    sq: jax.Array,
+    idx: jax.Array,
+    *,
+    length,
+    dt: int,
+    tau: int = 1,
+    impl: str = "auto",
+    block: int = 128,
+) -> tuple[jax.Array, jax.Array]:
+    """The serving tick: append ``dt`` points to B capacity masters held
+    as their append states (``ref.append_state``) → the grown states.
+
+    ``X`` (B, C) holds the grown series in [0, length + dt); ``sq`` /
+    ``idx`` are (E_max, k, B, C), rows minor. ``length`` is an operand:
+    one program per (B, C, dt, E_max). No stored candidate is
+    recomputed — the merge is pure selection over carried bits,
+    bit-identical to the cold build's accumulators
+    (``ref.master_append_sq``; the kernel path is
+    ``kernels/knn_append.py``).
+    """
     impl = _resolve(impl)
-    _tel("master_append", impl, E_max=int(dists.shape[0]),
-         L=int(x.shape[-1]), dt=int(x.shape[-1]) - int(dists.shape[1]))
+    _tel("master_append_sq", impl, B=int(X.shape[0]), C=int(X.shape[-1]),
+         E_max=int(sq.shape[1]), dt=int(dt))
     if impl == "ref":
-        return _ref.master_append(x, dists, idx, tau=tau)
-    from repro.kernels.knn_append import master_append as _append_k
-    return _append_k(x, dists, idx, tau=tau, block=block,
+        return _ref.master_append_sq(X, sq, idx, length=length, dt=dt,
+                                     tau=tau)
+    from repro.kernels.knn_append import master_append_sq as _append_k
+    return _append_k(X, sq, idx, length=length, dt=dt, tau=tau, block=block,
                      interpret=(impl == "interpret"))
 
 
@@ -362,15 +400,20 @@ def lookup_rho(
     Y: jax.Array,
     idx: jax.Array,
     w: jax.Array,
+    rows=None,
     *,
     offset: int = 0,
     impl: str = "auto",
     block: tuple[int, int] = (128, 128),
 ) -> jax.Array:
-    """Fused lookup + Pearson ρ per target → (N,) (paper §3.4 fused path)."""
+    """Fused lookup + Pearson ρ per target → (N,) (paper §3.4 fused path).
+
+    ``rows`` (an operand) keeps only the first ``rows`` table rows in
+    the correlation — the capacity-panel form; None uses them all.
+    """
     impl = _resolve(impl)
     _tel("lookup_rho", impl, N=int(Y.shape[0]))
     if impl == "ref":
-        return _ref.lookup_rho(Y, idx, w, offset=offset)
-    return _lookup_k.lookup_rho(Y, idx, w, offset=offset, block=block,
+        return _ref.lookup_rho(Y, idx, w, rows, offset=offset)
+    return _lookup_k.lookup_rho(Y, idx, w, rows, offset=offset, block=block,
                                 interpret=(impl == "interpret"))

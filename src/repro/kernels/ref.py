@@ -249,18 +249,24 @@ def lookup(
 
 @functools.partial(jax.jit, static_argnames=("offset",))
 def lookup_rho(
-    Y: jax.Array, idx: jax.Array, w: jax.Array, *, offset: int = 0
+    Y: jax.Array, idx: jax.Array, w: jax.Array, rows=None, *,
+    offset: int = 0
 ) -> jax.Array:
     """Fused lookup + Pearson ρ (paper §3.4 "on-the-fly" path).
 
     Compares Yhat[n, j] against the aligned truth Y[n, j + offset] and
     returns ρ per target, shape (N,). Never materializes Yhat in HBM on
-    the kernel path; this oracle just composes the two refs.
+    the kernel path; this oracle just composes the two refs. ``rows``
+    (an operand, may be traced) keeps only the first ``rows`` table
+    rows in the correlation: a capacity panel's tables are longer than
+    its valid prefix, and one program then serves every valid length.
     """
     yhat = lookup(Y, idx, w, offset=offset)
     Lp = idx.shape[0]
     yt = jax.lax.dynamic_slice_in_dim(Y, offset, Lp, axis=-1)
-    return pearson_rows(yhat, yt)
+    if rows is None:
+        return pearson_rows(yhat, yt)
+    return pearson_rows_masked(yhat, yt, rows)
 
 
 # --------------------------------------------------------------------------
@@ -622,6 +628,25 @@ def pearson_rows(a: jax.Array, b: jax.Array) -> jax.Array:
     denom = jnp.sqrt(va * vb)
     return jnp.where(denom > 0, cov / jnp.maximum(denom, 1e-30), 0.0)
 
+
+def pearson_rows_masked(a: jax.Array, b: jax.Array, n) -> jax.Array:
+    """``pearson_rows`` over the first ``n`` columns only (``n`` may be
+    traced). The sums run over the full width with the tail selected to
+    zero, so they round in another order than ``pearson_rows`` over an
+    ``n``-wide slice: the two agree to float32 rounding, not bit for bit.
+    """
+    live = jnp.arange(a.shape[-1]) < n
+    a = jnp.where(live, a.astype(jnp.float32), 0.0)
+    b = jnp.where(live, b.astype(jnp.float32), 0.0)
+    nf = jnp.asarray(n, jnp.float32)
+    am = jnp.where(live, a - jnp.sum(a, axis=-1, keepdims=True) / nf, 0.0)
+    bm = jnp.where(live, b - jnp.sum(b, axis=-1, keepdims=True) / nf, 0.0)
+    cov = jnp.sum(am * bm, axis=-1)
+    va = jnp.sum(am * am, axis=-1)
+    vb = jnp.sum(bm * bm, axis=-1)
+    denom = jnp.sqrt(va * vb)
+    return jnp.where(denom > 0, cov / jnp.maximum(denom, 1e-30), 0.0)
+
 # --------------------------------------------------------------------------
 # Incremental master append (the serving-path stream-in/merge primitive).
 #
@@ -666,33 +691,52 @@ def pearson_rows(a: jax.Array, b: jax.Array) -> jax.Array:
 #
 # The merge itself is then pure selection over carried bits, so the
 # Pallas variant (kernels/knn_append.py) shares these guarantees.
+#
+# Capacity and carried state. The tables span a capacity C ≥ L (rows past
+# a level's valid rows are inf / PAD_IDX) and the valid length is an
+# operand, so one program per (C, dt, E_max) serves every tick. The
+# stored candidates' squared distances (``append_state``: rule 1's
+# gathered recompute, one gather per lag and level) are the merge's
+# input; a serving session computes them once per master and carries the
+# merged squares forward (``master_append_sq``), so a tick gathers
+# nothing. The state holds the slots ahead of the rows, (E_max, k, C) a
+# series, so the kernel path's merge runs with the rows on the lanes.
 # --------------------------------------------------------------------------
 
 
-def append_new_row_slab(x, *, dt, E_max, tau):
+def append_lags(x, *, E_max, tau):
+    """The E_max lag views ``x[l·τ : l·τ + C]`` of a (C,) series (zero
+    past its end), shared by the append paths' distance chains."""
+    C = x.shape[-1]
+    xpad = jnp.pad(x.astype(jnp.float32), (0, (E_max - 1) * tau))
+    return [jax.lax.dynamic_slice_in_dim(xpad, l * tau, C, axis=-1)
+            for l in range(E_max)]
+
+
+def append_new_row_slab(x, length, *, dt, E_max, tau):
     """Negated-squared distances of the dt newest rows vs all columns.
 
-    Returns (E_max, dt, L_new) UNMASKED accumulator levels: entry
-    [e, r, j] equals the cold accumulator value at
-    (row Lp_old_e + r, col j) wherever the cold entry is valid (strict
-    chains are shape-independent). Row r of level e also supplies the
-    dt new COLUMNS of every old row by symmetry: negation and squaring
-    are exact and the per-lag chain order is identical, so
-    acc(i, j) == acc(j, i) bitwise. Shared by the ref and Pallas paths.
+    ``x`` is a (C,) series holding the grown series in [0, length + dt)
+    (``length`` the valid length before the append, an operand: one
+    program serves every length under the capacity C). Returns
+    (E_max, dt, C) UNMASKED accumulator levels: entry [e, r, j] equals
+    the cold accumulator value at (row Lp_old_e + r, col j) wherever the
+    cold entry is valid (strict chains are shape-independent). Row r of
+    level e also supplies the dt new COLUMNS of every old row by
+    symmetry: negation and squaring are exact and the per-lag chain
+    order is identical, so acc(i, j) == acc(j, i) bitwise. Shared by the
+    ref and Pallas paths.
     """
-    L_new = x.shape[-1]
-    xpad = jnp.pad(x.astype(jnp.float32), (0, (E_max - 1) * tau))
-    xls = [jax.lax.dynamic_slice_in_dim(xpad, l * tau, L_new, axis=-1)
-           for l in range(E_max)]
+    C = x.shape[-1]
+    xls = append_lags(x, E_max=E_max, tau=tau)
     outs = []
     for e in range(E_max):
-        Lp_old = L_new - dt - e * tau
-        Lp_new = L_new - e * tau
-        acc = jnp.zeros((dt, L_new), jnp.float32)
+        start = length - e * tau  # Lp_old of level e
+        acc = jnp.zeros((dt, C), jnp.float32)
         for l in range(e + 1):
             xl = xls[l]
-            df = xl[Lp_old:Lp_new, None] - xl[None, :]
-            acc = acc - strict_sq(df)
+            xr = jax.lax.dynamic_slice_in_dim(xl, start, dt, axis=-1)
+            acc = acc - strict_sq(xr[:, None] - xl[None, :])
         outs.append(acc)
     return jnp.stack(outs)
 
@@ -712,60 +756,96 @@ def normalize_garbage(nd, ik, rows):
     return jnp.where(finite, ik, garb)
 
 
-@functools.partial(jax.jit, static_argnames=("dt", "E_max", "tau"))
-def _master_append(x, dM, iM, *, dt, E_max, tau):
-    L_new = x.shape[-1]
-    L_old = L_new - dt
-    k_m = dM.shape[-1]
-    xpad = jnp.pad(x.astype(jnp.float32), (0, (E_max - 1) * tau))
-    xls = [jax.lax.dynamic_slice_in_dim(xpad, l * tau, L_new, axis=-1)
-           for l in range(E_max)]
-    slab = append_new_row_slab(x, dt=dt, E_max=E_max, tau=tau)
-    outs_d, outs_i = [], []
-    for e in range(E_max):  # level e ↔ embedding dim E = e+1
-        Lp_old = L_old - e * tau
-        Lp_new = L_new - e * tau
-        rows_o = jnp.arange(Lp_old, dtype=jnp.int32)
-        new_cols = Lp_old + jnp.arange(dt, dtype=jnp.int32)
-        # -- old rows: recompute stored candidates (strict chain) --------
-        i_o = iM[e, :Lp_old]
-        ok = jnp.isfinite(dM[e, :Lp_old])
-        jj = jnp.maximum(i_o, 0)  # clamp garbage/PAD for a safe gather
-        acc_s = jnp.zeros((Lp_old, k_m), jnp.float32)
+def place_new_rows(old, new, start, *, dt, fill, rows):
+    """A level's table: the old rows' merge, the dt new rows written at
+    ``start`` (Lp_old), and ``fill`` from ``start + dt`` (Lp_new) on."""
+    out = jax.lax.dynamic_update_slice_in_dim(old, new, start, axis=0)
+    return jnp.where((rows < start + dt)[:, None], out, fill)
+
+
+@functools.partial(jax.jit, static_argnames=("tau",))
+def append_state(x, dists, idx, *, tau):
+    """A master's append state: (sq, idx), each (E_max, k, C).
+
+    ``sq`` holds the stored candidates' squared distances, recomputed
+    with the cold build's strict chain (``strict_sq``, lag by lag), so
+    each is the bit pattern the cold accumulator held before its square
+    root; inf where the stored distance is inf (garbage or padding).
+    Both tables hold the slots ahead of the rows — (k, rows) per level —
+    so an append's merge runs with the rows on the vector lanes. A session
+    computes the state once per master (a gather per lag term and
+    level, the costly part) and the appends carry it forward.
+    """
+    E_max, C, k = dists.shape
+    xls = append_lags(x, E_max=E_max, tau=tau)
+    outs = []
+    for e in range(E_max):
+        jj = jnp.maximum(idx[e], 0)  # clamp garbage/PAD for a safe gather
+        acc = jnp.zeros((C, k), jnp.float32)
         for l in range(e + 1):
             xl = xls[l]
-            ds = xl[:Lp_old, None] - xl[jj]
-            acc_s = acc_s - strict_sq(ds)
-        # dt new columns of every old row — slab transpose, by symmetry
-        nd_new = slab[e, :, :Lp_old].T
-        cand_nd = jnp.concatenate([jnp.where(ok, acc_s, -_INF), nd_new],
-                                  axis=1)
+            acc = acc - strict_sq(xl[:, None] - xl[jj])
+        outs.append(jnp.where(jnp.isfinite(dists[e]), -acc, _INF).T)
+    return jnp.stack(outs), jnp.swapaxes(idx, 1, 2)
+
+
+def _append_sq(x, sq, idx, length, *, dt, tau):
+    """One series' append on its (E_max, k, C) state → the grown state."""
+    E_max, k_m, C = sq.shape
+    slab = append_new_row_slab(x, length, dt=dt, E_max=E_max, tau=tau)
+    rows = jnp.arange(C, dtype=jnp.int32)
+    outs_s, outs_i = [], []
+    for e in range(E_max):  # level e ↔ embedding dim E = e+1
+        Lp_old = length - e * tau
+        Lp_new = Lp_old + dt
+        new_cols = Lp_old + jnp.arange(dt, dtype=jnp.int32)
+        # -- old rows: stored candidates ∪ the dt new columns -----------
+        # Every row of the capacity is merged; rows ≥ Lp_old hold no
+        # stored candidate and are overwritten or filled below. The dt
+        # new columns of every old row are the slab transpose, by
+        # symmetry.
+        cand_nd = jnp.concatenate([-sq[e].T, slab[e].T], axis=1)
         cand_i = jnp.concatenate(
-            [i_o, jnp.broadcast_to(new_cols, (Lp_old, dt))], axis=1)
+            [idx[e].T, jnp.broadcast_to(new_cols, (C, dt))], axis=1)
         nd_o, pos = jax.lax.top_k(cand_nd, k_m)
         ik_o = normalize_garbage(
-            nd_o, jnp.take_along_axis(cand_i, pos, axis=1), rows_o)
+            nd_o, jnp.take_along_axis(cand_i, pos, axis=1), rows)
         # -- new rows: full slab rows, masked like the cold accumulator --
-        rows_n = Lp_old + jnp.arange(dt, dtype=jnp.int32)
-        colsL = jnp.arange(L_new, dtype=jnp.int32)[None, :]
-        inval = (colsL > Lp_new - 1) | (colsL == rows_n[:, None])
+        inval = (rows[None, :] > Lp_new - 1) | (rows[None, :]
+                                                == new_cols[:, None])
         nd_n, ik_n = _chunked_topk(jnp.where(inval, -_INF, slab[e]), k_m)
         # -- assemble the level ------------------------------------------
-        nd = jnp.concatenate([nd_o, nd_n], axis=0)
-        ik = jnp.concatenate([ik_o, ik_n], axis=0)
-        d_lvl = jnp.sqrt(jnp.maximum(-nd, 0.0))
-        outs_d.append(jnp.pad(d_lvl, ((0, L_new - Lp_new), (0, 0)),
-                              constant_values=jnp.inf))
-        outs_i.append(jnp.pad(ik, ((0, L_new - Lp_new), (0, 0)),
-                              constant_values=PAD_IDX))
-    return jnp.stack(outs_d), jnp.stack(outs_i)
+        outs_s.append(-place_new_rows(nd_o, nd_n, Lp_old, dt=dt,
+                                      fill=-_INF, rows=rows).T)
+        outs_i.append(place_new_rows(ik_o, ik_n, Lp_old, dt=dt,
+                                     fill=PAD_IDX, rows=rows).T)
+    return jnp.stack(outs_s), jnp.stack(outs_i)
+
+
+@functools.partial(jax.jit, static_argnames=("dt", "tau"))
+def master_append_sq(X, sq, idx, *, length, dt, tau):
+    """Append ``dt`` points to B series' append states → (sq, idx).
+
+    ``X`` (B, C) holds the grown series in [0, length + dt); ``sq`` /
+    ``idx`` (E_max, k, B, C) are the states (``append_state`` of each
+    series, on axis 2), valid for ``length`` points (an operand).
+    Returns both grown: each series' (E_max, k, C) transposed to
+    (C, k) per level is the cold build's pre-sqrt accumulators and
+    indices padded to C rows, bit for bit; the distances are
+    ``sqrt(max(sq, 0))``, as the cold build takes them. Pure selection
+    over carried bits: no stored candidate is recomputed. The reference
+    merges each row with ``lax.top_k`` in the (C, k) layout.
+    """
+    return jax.vmap(lambda x, s, i: _append_sq(x, s, i, length, dt=dt,
+                                               tau=tau),
+                    in_axes=(0, 2, 2), out_axes=2)(X, sq, idx)
 
 
 def check_append_args(x, dists, idx, tau: int) -> int:
-    """Validate master_append inputs; returns dt (the appended width)."""
+    """Validate ``ops.master_append`` inputs; returns dt (the appended
+    width)."""
     E_max, L_old, _ = dists.shape
-    L_new = int(x.shape[-1])
-    dt = L_new - L_old
+    dt = int(x.shape[-1]) - L_old
     if dt < 1:
         raise ValueError(f"append needs at least one new point, got dt={dt}")
     if idx.shape != dists.shape:
@@ -773,24 +853,3 @@ def check_append_args(x, dists, idx, tau: int) -> int:
             f"dists/idx shape mismatch: {dists.shape} vs {idx.shape}")
     num_embedded(L_old, E_max, tau)  # stored master must already be valid
     return dt
-
-
-def master_append(
-    x: jax.Array,
-    dists: jax.Array,
-    idx: jax.Array,
-    *,
-    tau: int = 1,
-) -> tuple[jax.Array, jax.Array]:
-    """Grow a multi-E master table to cover ``dt`` appended points.
-
-    ``x`` is the FULL appended series (length L_new); ``dists``/``idx``
-    are the stored ``all_knn_multi_e`` tables of its length-L_old
-    prefix, both (E_max, L_old, k_m) with uniform k (``panel_master``
-    masters). Returns the (E_max, L_new, k_m) tables, bit-identical to
-    ``all_knn_multi_e(x, E_max=E_max, tau=tau, k=k_m)`` at
-    O(Lp·(k_m+dt)) per level instead of O(Lp²).
-    """
-    dt = check_append_args(x, dists, idx, tau)
-    E_max = dists.shape[0]
-    return _master_append(x, dists, idx, dt=dt, E_max=E_max, tau=tau)

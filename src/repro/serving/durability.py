@@ -4,7 +4,7 @@ Under ``EDMServer(state_dir=...)`` every panel registration and every
 *accepted* append delta is made durable before its future resolves, so
 ``EDMServer.recover(state_dir)`` after any crash (kill -9 included)
 rebuilds every panel at its exact pre-crash library version — and by
-the append≡rebuild contract (``plan.panel_master_append`` is
+the append≡rebuild contract (``plan.panel_master_append_sq`` is
 bit-identical to a cold rebuild), every served answer after recovery is
 bit-identical to an uninterrupted session.
 
@@ -15,7 +15,7 @@ On-disk layout, one directory per panel under ``<state_dir>/panels/``::
       base.npy                   # the raw registered panel (float32)
       snap-0000000012/           # newest compaction snapshot (version 12)
         state.npz                # panel, valid mask, running screen stats
-        snap.json                # version, names, invalid_report
+        snap.json                # version, names, invalid_report, capacity
       wal-0000000012.log         # append records with version > 12
 
 The **fingerprint** reuses the PR-6 ``run_key`` hashing idiom: sha256
@@ -35,8 +35,9 @@ record and warns — exactly the PR-6 journal posture. Corruption
 
 **Compaction**: every ``compact_every`` logged records the owner
 snapshots the live ``Dataset`` state (panel + validity mask + running
-screen stats + invalid report — sufficient to continue ``append``
-bit-identically) into an atomic tmp+rename directory, rotates to a
+screen stats + invalid report + capacity — sufficient to continue
+``append`` bit-identically; a replay from the registered panel
+re-derives the capacity, since appends alone size it) into an atomic tmp+rename directory, rotates to a
 fresh WAL, and deletes older segments — recovery cost is
 O(snapshot + log tail), not O(append history).
 
@@ -177,7 +178,8 @@ def _restore_dataset(npz, snap: dict, on_invalid: str):
     from repro.edm.dataset import Dataset
     ds = Dataset.__new__(Dataset)
     ds.on_invalid = on_invalid
-    ds.panel = jnp.asarray(np.asarray(npz["panel"], np.float32))
+    panel = jnp.asarray(np.asarray(npz["panel"], np.float32))
+    L = int(panel.shape[1])
     ds.names = snap["names"]
     ds.valid = np.asarray(npz["valid"], bool)
     ds._stats = {"cnt": np.asarray(npz["cnt"]),
@@ -185,6 +187,7 @@ def _restore_dataset(npz, snap: dict, on_invalid: str):
                  "hi": np.asarray(npz["hi"])}
     ds.invalid_report = list(snap["invalid_report"])
     ds._embeddings = {}
+    ds._bind(panel, L, int(snap.get("capacity", L)))
     return ds
 
 
@@ -310,13 +313,14 @@ class PanelLog:
                 os.makedirs(tmp)
                 ds = sess.data
                 np.savez(os.path.join(tmp, "state.npz"),
-                         panel=np.asarray(ds.panel, np.float32),
+                         panel=np.asarray(ds.buffer, np.float32)[:, :ds.L],
                          valid=np.asarray(ds.valid, bool),
                          cnt=ds._stats["cnt"], lo=ds._stats["lo"],
                          hi=ds._stats["hi"])
                 with open(os.path.join(tmp, "snap.json"), "w") as f:
                     json.dump({"version": int(version), "names": ds.names,
-                               "invalid_report": ds.invalid_report}, f)
+                               "invalid_report": ds.invalid_report,
+                               "capacity": ds.capacity}, f)
                     f.flush()
                     os.fsync(f.fileno())
                 _fsync_file(os.path.join(tmp, "state.npz"))

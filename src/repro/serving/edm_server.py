@@ -50,6 +50,7 @@ serialized as nested lists (NaN encoded ``null`` per strict JSON).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import math
 import threading
@@ -86,6 +87,7 @@ class EDMServer:
                   else int(master_budget_mb * 2**20))
         self.registry = Registry(master_budget_bytes=budget)
         self.subscriptions = SubscriptionHub()
+        self._froze = False  # freeze_heap
         self.durability = (None if state_dir is None else Durability(
             state_dir, compact_every=compact_every, wal_fsync=wal_fsync,
             faults=faults))
@@ -132,6 +134,19 @@ class EDMServer:
         return srv
 
     def register_panel(self, name: str, panel, **kw) -> dict:
+        """Bind an (N, L) panel under ``name`` and serve it warm.
+
+        The panel and its kNN master are held at a capacity C ≥ L, the
+        valid length L an operand: every append and ``ccm`` program is
+        compiled once per C. A panel registers exact (C = L), so one
+        that is only queried keeps its shapes; its first append sizes C
+        to ``dataset.grown_capacity(L)`` — about a quarter more room —
+        and recompiles the serving programs once. Appends within C then
+        compile nothing, so warmed programs stay warm under a live
+        append stream; an append past C regrows the same way. Each
+        regrow counts in ``edm_capacity_regrows``. ``kw`` are
+        ``names=``, ``config=`` or ``EDMConfig`` fields.
+        """
         with telemetry.span("serve.register", panel=name):
             arr = np.asarray(panel, np.float32)
             info = self.registry.register(name, arr, **kw)
@@ -226,11 +241,32 @@ class EDMServer:
             self.durability.fsync_all()
         return ok
 
+    def freeze_heap(self) -> int:
+        """Take every object alive now out of Python's cyclic garbage
+        collection (``gc.freeze``, after one last collection); returns
+        how many. ``close`` thaws them.
+
+        Call it once the server is warm: panels registered, programs
+        compiled. A warm server's process holds a few hundred thousand
+        long-lived objects (imports, the compiled programs' caches), and
+        every full collection walks them all with every thread stopped —
+        about 0.1 s, which each request in flight then waits. Frozen,
+        they are never walked again; what the server allocates later is
+        collected as before.
+        """
+        gc.collect()
+        gc.freeze()
+        self._froze = True
+        return gc.get_freeze_count()
+
     def close(self) -> None:
         self.scheduler.close()
         self.subscriptions.close_all()
         if self.durability is not None:
             self.durability.close()
+        if self._froze:
+            gc.unfreeze()
+            self._froze = False
 
     def __enter__(self):
         return self

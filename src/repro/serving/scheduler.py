@@ -81,8 +81,10 @@ Telemetry: ``serve_queue_depth`` / ``serve_queued_bytes`` /
 / ``serve_deadline_exceeded`` / ``serve_quarantined`` counters, the
 queue-wait and execution clocks ``serve_queue_wait_seconds`` over
 ``serve_claimed`` (submit to claim, per request taken into a batch) and
-``serve_exec_seconds`` over ``serve_batches`` (per executed batch), and
-a span per batch with per-request events.
+``serve_exec_seconds`` over ``serve_batches`` (per executed batch), the
+append clock ``serve_append_seconds`` over ``serve_appends`` (each
+applied append, its WAL write included, under a ``serve.append`` span),
+and a span per batch with per-request events.
 """
 
 from __future__ import annotations
@@ -711,22 +713,27 @@ class Scheduler:
             self.faults.check("launch_error", detail=f"{r.op}:{r.panel}")
             self.faults.check("launch_oom", detail=f"{r.op}:{r.panel}")
         if r.op == "append":
-            delta = np.asarray(p["delta"], np.float32)
-            records = sess.append(delta)
-            new_version = entry.version + 1
-            if entry.wal is not None:
-                # WAL before the future resolves. On write failure the
-                # in-memory library is ahead of the log: quarantine —
-                # serving it would break the recovery bit-contract.
-                try:
-                    entry.wal.log_append(delta, new_version)
-                except Exception as exc:
-                    self._quarantine(entry.name, exc)
-                    raise
-                if entry.wal.should_compact():
-                    entry.wal.compact(sess, new_version)
-            entry.version = new_version
+            t0 = time.perf_counter()
+            with telemetry.span("serve.append", panel=entry.name):
+                delta = np.asarray(p["delta"], np.float32)
+                records = sess.append(delta)
+                new_version = entry.version + 1
+                if entry.wal is not None:
+                    # WAL before the future resolves. On write failure
+                    # the in-memory library is ahead of the log:
+                    # quarantine — serving it would break the recovery
+                    # bit-contract.
+                    try:
+                        entry.wal.log_append(delta, new_version)
+                    except Exception as exc:
+                        self._quarantine(entry.name, exc)
+                        raise
+                    if entry.wal.should_compact():
+                        entry.wal.compact(sess, new_version)
+                entry.version = new_version
             telemetry.counter("serve_appends").inc()
+            telemetry.counter("serve_append_seconds").inc(
+                time.perf_counter() - t0)
             out = {"records": records, "version": entry.version,
                    "N": sess.data.N, "L": sess.data.L}
             if self.subscriptions is not None:

@@ -73,6 +73,7 @@ class PanelEntry:
             "name": self.name,
             "N": self.sess.data.N,
             "L": self.sess.data.L,
+            "capacity": self.sess.data.capacity,
             "version": self.version,
             "num_invalid": self.sess.data.num_invalid,
             "E_max": self.sess.config.E_max,

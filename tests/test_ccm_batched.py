@@ -63,7 +63,7 @@ def test_batched_empty_library_axis():
     sess = EDM(X, EDMConfig(E_max=4))
     sess.optimal_E()
     iM = sess._cache["master"][1]
-    rho_m = edm_plan.ccm_group_from_master_batched(
+    rho_m = edm_plan.ccm_group_from_master(
         X[:0], iM[:0, 1], X, E=2, tau=1, Tp=0, k=3, impl="ref")
     assert rho_m.shape == (0, 4)
 
@@ -86,9 +86,9 @@ def test_master_batched_bit_invariant_and_matches_per_series():
     sess.optimal_E()
     dM, iM, k_m, lv = sess._cache["master"]
     E = 3
-    runs = [edm_plan.ccm_group_from_master_batched(
-        X, iM[:, E - 1], X, E=E, tau=1, Tp=0, k=E + 1, impl="ref",
-        batch_libs=B) for B in (1, 4, 6)]
+    launch = edm_plan.make_master_group_launch(
+        X, iM[:, E - 1], X, E=E, tau=1, Tp=0, k=E + 1, impl="ref")
+    runs = [ccm.drive_batched(6, B, launch) for B in (1, 4, 6)]
     for got in runs[1:]:
         np.testing.assert_array_equal(runs[0], got)
     legacy = np.asarray(edm_plan.ccm_group_from_master(

@@ -22,9 +22,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import ccm
+from repro.edm import plan as edm_plan
 from repro.edm.plan import panel_master
 from repro.kernels import ref
-from repro.kernels.knn_append import _master_append
+from repro.kernels.knn_append import master_append_sq
 from repro.kernels.knn_batch import knn_batch
 from repro.kernels.knn_multi_e import knn_multi_e
 from repro.kernels.lookup import lookup_rho
@@ -122,15 +123,45 @@ def test_topk_select_sizes_compiles(one_chip, L):
         exclude_self=True, block=(8, 512), interpret=False))
 
 
+def _lower_master_append(one_chip, C, B, dt=8):
+    """The serving tick of B series held at capacity C: one program per
+    (B, C, dt, E_max), the valid length an int32 operand."""
+    return master_append_sq.lower(
+        _spec(one_chip, (B, C)), _spec(one_chip, (E_MAX, K_MASTER, B, C)),
+        _spec(one_chip, (E_MAX, K_MASTER, B, C), jnp.int32),
+        length=_spec(one_chip, (), jnp.int32), dt=dt, tau=1, block=128,
+        interpret=False)
+
+
 @pytest.mark.parametrize("L", LENGTHS)
 def test_master_append_compiles(one_chip, L):
-    dt = 8
-    L_old = L - dt
-    _compile_ok(_master_append.lower(
-        _spec(one_chip, (L,)), _spec(one_chip, (E_MAX, L_old, K_MASTER)),
-        _spec(one_chip, (E_MAX, L_old, K_MASTER), jnp.int32), dt=dt,
-        E_max=E_MAX, tau=1, block=128, interpret=False),
-        kernels=2 * E_MAX)
+    B = 154 if L < 4096 else 8
+    _compile_ok(_lower_master_append(one_chip, L + 256, B), kernels=2)
+
+
+@pytest.mark.parametrize("L", LENGTHS)
+def test_lookup_rho_capacity_compiles(one_chip, L):
+    """``lookup_rho`` with its valid row count an SMEM operand: the
+    capacity panel's form, one program for every length under C."""
+    E = 10
+    N = 154 if L < 4096 else 256
+    C = L + 256
+    Lp = C - (E - 1)
+    _compile_ok(lookup_rho.lower(
+        _spec(one_chip, (N, C)), _spec(one_chip, (Lp, E + 1), jnp.int32),
+        _spec(one_chip, (Lp, E + 1)), _spec(one_chip, (), jnp.int32),
+        offset=E - 1, block=(128, 128), interpret=False))
+
+
+def test_ccm_batch_step_compiles(one_chip):
+    """The served ``ccm_batch`` launch on a Fish1_Normo-shaped capacity
+    panel: library gather, derived tables and ρ in one program."""
+    N, C, B, E = 154, 2048, 32, 6
+    _compile_ok(edm_plan._ccm_batch_step.lower(
+        _spec(one_chip, (N, C)),
+        _spec(one_chip, (N, E_MAX, C, K_MASTER), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (), jnp.int32),
+        E=E, tau=1, Tp=0, k=E + 1, impl="pallas"))
 
 
 @pytest.mark.parametrize("L", LENGTHS)
@@ -155,14 +186,21 @@ def _lower_panel_master(one_chip):
                               tau=1, k=K_MASTER, impl="pallas")
 
 
+def _lower_knn_append(one_chip):
+    return _lower_master_append(one_chip, 2048, 4)
+
+
 @pytest.mark.parametrize("lower,kernel", [
     (_lower_group_step, "knn_batch"),
     (_lower_panel_master, "knn_multi_e"),
+    (_lower_knn_append, "knn_append"),
+    (_lower_knn_append, "knn_append_fold"),
 ])
 def test_kernel_programs_are_named(one_chip, lower, kernel):
     """The program around each kNN kernel carries the kernel's name, which
-    the HLO instruction takes (``%knn_batch.N``, ``%knn_multi_e.N``), so a
-    trace tells the kernels apart by name alone."""
+    the HLO instruction takes (``%knn_batch.N``, ``%knn_multi_e.N``,
+    ``%knn_append.N``, ``%knn_append_fold.N``), so a trace tells the
+    kernels apart by name alone."""
     funcs = set(re.findall(r"func\.func (?:private |public )?@([\w.]+)",
                            lower(one_chip).as_text()))
     assert kernel in funcs

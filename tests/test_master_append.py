@@ -12,8 +12,9 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import telemetry
 from repro.edm.dataset import Dataset, screen_panel, series_stats
-from repro.edm.plan import panel_master_append
+from repro.edm.plan import pad_master, panel_master_append
 from repro.edm.session import EDM
 from repro.kernels import ops, ref
 
@@ -111,6 +112,18 @@ def test_panel_append_matches_panel_master(rng):
     _assert_bit_equal(grown, cold, "(panel)")
 
 
+def test_panel_append_kernel_path_matches_panel_master(rng):
+    """The kernel path over a panel whose series fill no whole block of
+    the fold (11 = 8 + 3), τ = 2: the grown master equals the cold one."""
+    X = jnp.asarray(np.round(rng.normal(size=(11, 130)) * 2) / 2,
+                    jnp.float32)              # ties
+    from repro.edm.plan import panel_master
+    dM, iM = panel_master(X[:, :112], E_max=4, tau=2, k=9, impl="ref")
+    grown = panel_master_append(X, dM, iM, tau=2, impl="interpret")
+    cold = panel_master(X, E_max=4, tau=2, k=9, impl="ref")
+    _assert_bit_equal(grown, cold, "(panel, kernel path)")
+
+
 def test_append_args_validated(rng):
     x = _series(rng, 50, "rand")
     d, i = ref.all_knn_multi_e(x, E_max=3, tau=1, k=5)
@@ -132,8 +145,11 @@ def test_session_append_master_bit_matches_cold_session(rng):
     warm.append(full[:, 100:])
     cold = EDM(full, E_max=4, cache=True)
     cold._master(warm._cache["master"][3])
-    _assert_bit_equal(warm._cache["master"][:2], cold._cache["master"][:2],
-                      "(session master)")
+    # The grown master is held at the capacity the append sized: the
+    # cold tables in its first L rows, inf / PAD_IDX below.
+    _assert_bit_equal(warm._cache["master"][:2],
+                      pad_master(*cold._cache["master"][:2],
+                                 warm.data.capacity), "(session master)")
     # ...and every consumer downstream of the master agrees too.
     np.testing.assert_array_equal(warm.optimal_E()[1], cold.optimal_E()[1])
     np.testing.assert_array_equal(np.asarray(warm.ccm(0, 2)),
@@ -216,5 +232,100 @@ def test_session_append_drop_compacts_master_rows(rng):
     ref_full[:, 100:] = np.asarray(bad)[keep]
     cold = EDM(ref_full, E_max=3, cache=True)
     cold._master(3)
+    _assert_bit_equal(sess._cache["master"][:2],
+                      pad_master(*cold._cache["master"][:2],
+                                 sess.data.capacity), "(drop compaction)")
+
+
+# ------------------------------------------------------------ capacity
+#
+# A live panel is held at a capacity C ≥ L: the master's rows past a
+# level's valid rows are inf / PAD_IDX, and the append program reads the
+# valid length as an operand. Append at C must equal the cold build of
+# the grown series at the same C, bit for bit — which is the exact cold
+# build padded to C rows.
+
+
+def _at_capacity(tables, C):
+    d, i = tables
+    pad = ((0, 0), (0, C - d.shape[1]), (0, 0))
+    return (jnp.pad(d, pad, constant_values=jnp.inf),
+            jnp.pad(i, pad, constant_values=ref.PAD_IDX))
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("L_old,dt,E_max,tau,C", [
+    (90, 8, 3, 1, 128),
+    (97, 5, 4, 2, 110),
+    (60, 1, 6, 1, 64),
+    (24, 3, 6, 1, 40),    # k_m beyond deep levels' candidates: garbage
+])
+@pytest.mark.parametrize("kind", ["rand", "tie"])
+def test_append_at_capacity_bit_identical_to_cold_build_at_capacity(
+        rng, impl, L_old, dt, E_max, tau, C, kind):
+    L_new = L_old + dt
+    x = _series(rng, L_new, kind)
+    k = 22 if L_old == 24 else min(L_old - (E_max - 1) * tau + 3, 20)
+    buf = jnp.zeros(C, jnp.float32).at[:L_new].set(x)
+    sq, it = ref.append_state(buf, *_at_capacity(
+        ref.all_knn_multi_e(x[:L_old], E_max=E_max, tau=tau, k=k), C),
+        tau=tau)
+    sq, it = ops.master_append_sq(buf[None], sq[:, :, None], it[:, :, None],
+                                  length=jnp.int32(L_old), dt=dt, tau=tau,
+                                  impl=impl)
+    grown = (jnp.sqrt(jnp.maximum(sq[:, :, 0], 0.0)).swapaxes(1, 2),
+             it[:, :, 0].swapaxes(1, 2))
+    cold = _at_capacity(ref.all_knn_multi_e(x, E_max=E_max, tau=tau, k=k),
+                        C)
+    _assert_bit_equal(grown, cold, f"(capacity {C}, L {L_old}+{dt}, "
+                                   f"E_max={E_max}, tau={tau}, {kind})")
+
+
+def test_session_appends_within_capacity_match_cold_session_at_capacity(
+        rng):
+    full = rng.normal(size=(5, 124)).astype(np.float32)
+    warm = EDM(full[:, :100], E_max=4, cache=True)
+    warm.optimal_E()
+    for a in (100, 108, 116):
+        warm.append(full[:, a:a + 8])
+    cold = EDM(full[:, :100], E_max=4, cache=True)
+    cold.append(full[:, 100:])             # no master yet: built at C
+    cold._master(4)
+    assert warm.data.capacity == cold.data.capacity == 256
+    assert warm.data.L == 124
+    assert warm.stats["capacity_regrows"] == 1     # the first append's
+    _assert_bit_equal(warm._cache["master"][:2], cold._cache["master"][:2],
+                      "(session master at capacity)")
+    pairs = [(0, 2), (3, 1), (4, 4)]
+    got = warm.ccm_batch(pairs, E=3)
+    np.testing.assert_array_equal(got, cold.ccm_batch(pairs, E=3))
+    # Against the exact-L program the masked sums round in another order.
+    exact = EDM(full, E_max=4, cache=True).ccm_batch(pairs, E=3)
+    np.testing.assert_allclose(got, exact, rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(warm.data.panel), full)
+
+
+def test_append_past_capacity_regrows_once(rng):
+    full = rng.normal(size=(4, 300)).astype(np.float32)
+    sess = EDM(full[:, :100], E_max=3, cache=True)
+    sess.optimal_E()
+    sess.append(full[:, 100:108])              # sizes C = 256
+    assert sess.data.capacity == 256
+    with telemetry.record() as rec:
+        sess.append(full[:, 108:250])          # 250 ≤ 256: no regrow
+        sess.append(full[:, 250:258])          # 258 > 256: regrow
+        sess.append(full[:, 258:300])          # 300 ≤ 384: no regrow
+    assert rec.counter_delta("edm_capacity_regrows") == 1
+    assert sess.data.capacity == 384           # 1.25 · 258, to 128s
+    assert sess._cache["master"][0].shape[2] == 384
+    cold = EDM(full[:, :100], E_max=3, cache=True)
+    cold.append(full[:, 100:])
+    cold._master(3)
     _assert_bit_equal(sess._cache["master"][:2], cold._cache["master"][:2],
-                      "(drop compaction)")
+                      "(regrown master)")
+    pairs = [(0, 1), (2, 3), (3, 0)]
+    got = sess.ccm_batch(pairs, E=2)
+    np.testing.assert_array_equal(got, cold.ccm_batch(pairs, E=2))
+    np.testing.assert_allclose(
+        got, EDM(full, E_max=3, cache=True).ccm_batch(pairs, E=2),
+        rtol=0, atol=1e-5)

@@ -35,8 +35,15 @@ def panel():
 PAIRS = [(0, 2), (1, 3), (0, 4), (2, 5), (1, 2), (3, 0)]
 
 
-def _direct(panel):
-    sess = EDM(panel, E_max=4, cache=True)
+def _direct(panel, grown_from=None):
+    """A direct session on ``panel``; with ``grown_from`` L0, the one a
+    server's appends give it — registered at L0, the rest appended —
+    which holds it at the capacity the first append sizes."""
+    if grown_from is None:
+        sess = EDM(panel, E_max=4, cache=True)
+    else:
+        sess = EDM(panel[:, :grown_from], E_max=4, cache=True)
+        sess.append(panel[:, grown_from:])
     sess.optimal_E()
     return sess
 
@@ -177,7 +184,7 @@ def test_append_sequences_against_inflight_compatible_batch(panel):
         assert rec.counter_delta("serve_appends") == 1
         assert rec.counter_delta("edm_knn_master_appends") == 1  # no rebuild
         d_old = _direct(old)
-        d_new = _direct(panel)
+        d_new = _direct(panel, grown_from=280)
         for (l, t), f in zip(PAIRS[:3], pre):
             np.testing.assert_array_equal(
                 np.asarray(f.result(timeout=5)),
@@ -202,6 +209,151 @@ def test_append_rejects_nan_delta_and_names_series(panel):
             fut.result(timeout=5)
         # server state untouched: panel length unchanged, next op fine
         assert srv.registry.get("p").sess.data.L == 280
+
+
+# ------------------------------------------------------- capacity panels
+#
+# A panel registers exact; its first append sizes a capacity C with room
+# to grow (``dataset.grown_capacity``), and the append and ccm programs
+# then read the valid length as an operand until L passes C.
+
+
+class _Compiles:
+    """Backend compiles (XLA), counted from JAX's compile events while
+    ``on`` — the harness's ``CompileClock`` count."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax
+
+        self.n, self.on = 0, False
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+
+    def _event(self, event, duration, **_):
+        if self.on and event == self.EVENT:
+            self.n += 1
+
+
+def _drain(srv, futs):
+    while srv.scheduler.drain_once():
+        pass
+    return [f.result(timeout=5) for f in futs]
+
+
+def _ccm_round(srv):
+    return _drain(srv, [srv.submit("ccm", "p", lib=l, target=t, E=3)
+                        for l, t in PAIRS]
+                  + [srv.submit("ccm", "p", lib=0, target=5, E=2)])
+
+
+def _session_at(grown, L0, C):
+    """A session on ``grown`` whose master is built cold at capacity C
+    (``_direct`` with the rest appended before the master exists)."""
+    sess = _direct(grown, grown_from=L0 if grown.shape[1] > L0 else None)
+    assert sess.data.capacity == C
+    return sess
+
+
+def test_appends_within_capacity_compile_nothing(panel):
+    compiles = _Compiles()
+    with EDMServer(autostart=False) as srv:
+        srv.register_panel("p", panel[:, :268], E_max=4, cache=True)
+        _drain(srv, [srv.submit("optimal_E", "p")])
+        _drain(srv, [srv.submit("append", "p", delta=panel[:, 268:272])])
+        _ccm_round(srv)                       # warms every (E, batch) shape
+        C = srv.registry.get("p").sess.data.capacity
+        compiles.on = True
+        with telemetry.record() as rec:
+            for a in range(272, 300, 4):      # 7 appends within capacity
+                _drain(srv, [srv.submit("append", "p",
+                                        delta=panel[:, a:a + 4])])
+                got = _ccm_round(srv)
+        compiles.on = False
+        assert srv.registry.get("p").sess.data.L == 300
+    assert C == 384
+    assert compiles.n == 0, f"{compiles.n} compiles after warm-up"
+    assert rec.counter_delta("serve_appends") == 7
+    assert rec.counter_delta("edm_capacity_regrows") == 0
+    assert rec.counter_delta("serve_append_seconds") > 0
+    cold = _session_at(panel, 268, C)
+    want = [cold.ccm_batch([p], E=3)[0] for p in PAIRS] + [
+        cold.ccm_batch([(0, 5)], E=2)[0]]
+    np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+
+
+def test_served_ccm_after_appends_matches_ref_on_grown_panel(panel):
+    """Each answer equals the cold session at the same capacity on the
+    panel as of its version, bit for bit, and the plain jnp engine
+    (``impl="ref"``, exact shapes, no master) to float32 rounding."""
+    import jax.numpy as jnp
+
+    from repro.core.ccm import ccm_group_batched
+
+    L0, dt = 260, 10
+    with EDMServer(autostart=False) as srv:
+        srv.register_panel("p", panel[:, :L0], E_max=4, cache=True)
+        _drain(srv, [srv.submit("optimal_E", "p")])
+        answers = {}
+        for v in range(5):
+            if v:
+                _drain(srv, [srv.submit(
+                    "append", "p", delta=panel[:, L0 + (v - 1) * dt:
+                                                L0 + v * dt])])
+            answers[v] = _ccm_round(srv)
+    for v, got in answers.items():
+        grown = panel[:, :L0 + v * dt]
+        cold = _session_at(grown, L0, 384 if v else L0)
+        want = [cold.ccm_batch([p], E=3)[0] for p in PAIRS] + [
+            cold.ccm_batch([(0, 5)], E=2)[0]]
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want,
+                                      err_msg=f"version {v}")
+        X = jnp.asarray(grown)
+        plain = [float(np.asarray(ccm_group_batched(
+            X[l:l + 1], X[t:t + 1], E=E, tau=1, Tp=0, impl="ref"))[0, 0])
+            for (l, t), E in zip(PAIRS + [(0, 5)], [3] * len(PAIRS) + [2])]
+        np.testing.assert_allclose(got, plain, rtol=0, atol=1e-5,
+                                   err_msg=f"version {v}")
+
+
+@pytest.mark.parametrize("compact_every", [64, 2])
+def test_recover_restores_capacity_and_panel(panel, tmp_path,
+                                             compact_every):
+    """Replayed from the WAL alone, or from a snapshot plus its tail."""
+    with EDMServer(autostart=False, state_dir=str(tmp_path),
+                   compact_every=compact_every) as srv:
+        srv.register_panel("p", panel[:, :100], E_max=4, cache=True)
+        with telemetry.record() as rec:
+            for a in range(100, 300, 40):     # sized at 140, regrown at 260
+                _drain(srv, [srv.submit("append", "p",
+                                        delta=panel[:, a:a + 40])])
+        sess = srv.registry.get("p").sess
+        want_C = sess.data.capacity
+        before = _ccm_round(srv)
+    assert want_C == 384 and rec.counter_delta("edm_capacity_regrows") == 2
+    rec = EDMServer.recover(str(tmp_path), autostart=False)
+    try:
+        data = rec.registry.get("p").sess.data
+        assert (data.capacity, data.L) == (want_C, 300)
+        np.testing.assert_array_equal(np.asarray(data.panel), panel)
+        np.testing.assert_array_equal(
+            np.asarray(_ccm_round(rec), np.float32),
+            np.asarray(before, np.float32))
+    finally:
+        rec.close()
+
+
+def test_freeze_heap_holds_the_warm_heap_until_close(panel):
+    """A warm server's objects leave the cyclic collector; serving goes
+    on bit for bit, and closing the server thaws them."""
+    import gc
+
+    with EDMServer(autostart=False) as srv:
+        srv.register_panel("p", panel, E_max=4, cache=True)
+        before = _ccm_round(srv)
+        assert srv.freeze_heap() > 0 and gc.get_freeze_count() > 0
+        assert _ccm_round(srv) == before
+    assert gc.get_freeze_count() == 0
 
 
 # --------------------------------------------------------- threaded mode
@@ -242,7 +394,7 @@ def test_append_during_inflight_traffic_is_linearized(panel):
     append resolves matches post-append exactly."""
     old, delta = panel[:, :280], panel[:, 280:]
     d_old = _direct(old)
-    d_new = _direct(panel)
+    d_new = _direct(panel, grown_from=280)
     pre = {p: d_old.ccm_batch([p], E=3)[0] for p in PAIRS}
     post = {p: d_new.ccm_batch([p], E=3)[0] for p in PAIRS}
     with EDMServer() as srv:

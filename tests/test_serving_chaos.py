@@ -61,12 +61,14 @@ def _panel():
 
 
 def oracle(k: int) -> dict:
-    """Singleton answers at commit count ``k`` (cold session)."""
+    """Singleton answers at commit count ``k`` (cold session grown by the
+    same ``k`` appends before its master exists, so at the capacity the
+    served panel holds)."""
     if k not in _ORACLE:
         panel, delta = _panel()
-        grown = (panel if k == 0
-                 else np.concatenate([panel] + [delta] * k, axis=1))
-        sess = EDM(grown, EDMConfig(E_max=3, cache=True))
+        sess = EDM(panel, EDMConfig(E_max=3, cache=True))
+        for _ in range(k):
+            sess.append(delta)
         _ORACLE[k] = {E: [np.float32(v)
                           for v in sess.ccm_batch(WATCH, E=E)]
                       for E in ES}

@@ -49,9 +49,6 @@ def _drain_all(srv):
         pass
 
 
-def _grown(panel, deltas, k):
-    return (panel if k == 0
-            else np.concatenate([panel, *deltas[:k]], axis=1))
 
 
 def _served_ccm(srv, name, pairs):
@@ -62,8 +59,13 @@ def _served_ccm(srv, name, pairs):
     return [np.float32(f.result()) for f in futs]
 
 
-def _oracle_ccm(grown, pairs):
-    sess = EDM(grown, EDMConfig(**CFG))
+def _oracle_ccm(panel, applied, pairs):
+    """The quiesced answers of a cold session on ``panel`` grown by the
+    ``applied`` deltas, appended in turn before its master exists — so
+    it holds the capacity the server's panel holds."""
+    sess = EDM(panel, EDMConfig(**CFG))
+    for d in applied:
+        sess.append(d)
     return [np.float32(v) for v in sess.ccm_batch(pairs, E=E_REQ)]
 
 
@@ -87,7 +89,7 @@ def test_recover_bit_identical_after_appends(tmp_path, panel, deltas):
         entry = rec.registry.get("p")
         assert entry.version == 3
         got = _served_ccm(rec, "p", PAIRS)
-        want = _oracle_ccm(_grown(panel, deltas, 3), PAIRS)
+        want = _oracle_ccm(panel, deltas[:3], PAIRS)
         assert got == want  # bitwise: float32 equality
     finally:
         rec.close()
@@ -107,7 +109,7 @@ def test_recovered_panel_keeps_appending_bit_identically(
         _drain_all(rec)
         assert f.result()["version"] == 2
         got = _served_ccm(rec, "p", PAIRS)
-        want = _oracle_ccm(_grown(panel, deltas, 2), PAIRS)
+        want = _oracle_ccm(panel, deltas[:2], PAIRS)
         assert got == want
     finally:
         rec.close()
@@ -136,7 +138,7 @@ def test_compaction_bounds_replay_and_gcs_segments(
         assert info["snapshot"] == 4 and info["replayed"] == 1
         assert info["version"] == 5
         got = _served_ccm(rec, "p", PAIRS)
-        assert got == _oracle_ccm(_grown(panel, deltas, 5), PAIRS)
+        assert got == _oracle_ccm(panel, deltas[:5], PAIRS)
     finally:
         rec.close()
 
@@ -165,7 +167,7 @@ def test_truncated_wal_tail_recovers_to_last_record_and_warns(
         info = rec.recovery_report["p"]
         assert info["version"] == 2 and info["torn_tail_bytes"] > 0
         got = _served_ccm(rec, "p", PAIRS)
-        assert got == _oracle_ccm(_grown(panel, deltas, 2), PAIRS)
+        assert got == _oracle_ccm(panel, deltas[:2], PAIRS)
         # The post-recovery rotation truncated the torn tail for good:
         # a second recovery is clean.
         rec.close()
@@ -202,7 +204,7 @@ def test_recover_evicted_master_panel(tmp_path, panel, deltas):
     try:
         assert rec.recovery_report["p"]["version"] == 1
         got = _served_ccm(rec, "p", PAIRS)
-        assert got == _oracle_ccm(_grown(panel, deltas, 1), PAIRS)
+        assert got == _oracle_ccm(panel, deltas[:1], PAIRS)
     finally:
         rec.close()
 
@@ -230,13 +232,13 @@ def test_subscription_reregistered_post_restart(tmp_path, panel, deltas):
         sub = f.result()
         assert sub["version"] == 1
         assert [np.float32(v) for v in sub["rho"]] == _oracle_ccm(
-            _grown(panel, deltas, 1), watch)
+            panel, deltas[:1], watch)
         rec.submit("append", "p", delta=deltas[1])
         _drain_all(rec)
         ticks = rec.subscription(sub["id"]).poll(timeout=1.0)
         assert ticks and ticks[-1]["version"] == 2
         assert [np.float32(v) for v in ticks[-1]["rho"]] == _oracle_ccm(
-            _grown(panel, deltas, 2), watch)
+            panel, deltas[:2], watch)
     finally:
         rec.close()
 
@@ -321,7 +323,7 @@ def test_wal_write_failure_quarantines_panel(tmp_path, panel, deltas):
     try:
         assert rec2.recovery_report["p"]["version"] == 0
         got = _served_ccm(rec2, "p", PAIRS)
-        assert got == _oracle_ccm(panel, PAIRS)
+        assert got == _oracle_ccm(panel, [], PAIRS)
     finally:
         rec2.close()
 
@@ -395,6 +397,6 @@ def test_kill9_mid_append_stream_recovers_bit_identically(
         grown = np.concatenate([panel] + [delta] * v, axis=1)
         assert rec.registry.get("kp").sess.data.L == grown.shape[1]
         got = _served_ccm(rec, "kp", PAIRS)
-        assert got == _oracle_ccm(grown, PAIRS)
+        assert got == _oracle_ccm(panel, [delta] * v, PAIRS)
     finally:
         rec.close()

@@ -41,6 +41,17 @@ def _panel(n, length, seed, tie=False):
     return x
 
 
+def _grown(full, L0, L, **cfg):
+    """The direct session a server's panel is after its appends:
+    registered at L0, the rest appended (the first append sizes its
+    capacity), its master then built at that capacity."""
+    sess = EDM(full[:, :L0], **cfg)
+    if L > L0:
+        sess.append(full[:, L0:L])
+    sess.optimal_E()
+    return sess
+
+
 def _drain_all(srv):
     sizes = []
     while True:
@@ -123,8 +134,7 @@ def stress_world():
     for p, full in data.items():
         per_version = []
         for v in range(DELTAS + 1):
-            sess = EDM(full[:, : L0 + v * DT], E_max=4, cache=True)
-            sess.optimal_E()
+            sess = _grown(full, L0, L0 + v * DT, E_max=4, cache=True)
             per_version.append({pair: sess.ccm_batch([pair], E=E_REQ)[0]
                                 for pair in PAIRS})
         oracles[p] = per_version
@@ -464,8 +474,7 @@ def test_failed_append_neither_wedges_queue_nor_leaks_barrier():
     bad[1, 3] = np.nan
     d_old = EDM(old, E_max=3, cache=True)
     d_old.optimal_E()
-    d_new = EDM(np.concatenate([old, good], axis=1), E_max=3, cache=True)
-    d_new.optimal_E()
+    d_new = _grown(full, 140, 150, E_max=3, cache=True)
     with EDMServer(autostart=False) as srv:
         srv.register_panel("p", old, names=[f"s{i}" for i in range(5)],
                            E_max=3, cache=True)
@@ -509,9 +518,7 @@ def test_subscription_ticks_bit_match_direct_sessions():
     watch = PAIRS[:3]
     sessions = []
     for v in range(3):
-        s = EDM(full[:, : 140 + v * 8], E_max=3, cache=True)
-        s.optimal_E()
-        sessions.append(s)
+        sessions.append(_grown(full, 140, 140 + v * 8, E_max=3, cache=True))
     with EDMServer(autostart=False) as srv:
         srv.register_panel("p", old, E_max=3, cache=True)
         srv.submit("optimal_E", "p")
@@ -550,10 +557,8 @@ def test_subscription_survives_eviction_bitwise():
     full = _panel(5, 152, seed=14)
     old, d1, d2 = full[:, :140], full[:, 140:146], full[:, 146:152]
     watch = PAIRS[:2]
-    g1 = EDM(full[:, :146], E_max=3, cache=True)
-    g1.optimal_E()
-    g2 = EDM(full, E_max=3, cache=True)
-    g2.optimal_E()
+    g1 = _grown(full, 140, 146, E_max=3, cache=True)
+    g2 = _grown(full, 140, 152, E_max=3, cache=True)
     with EDMServer(autostart=False) as srv:
         srv.register_panel("p", old, E_max=3, cache=True)
         fs = srv.submit("subscribe", "p", pairs=watch, E=2)
@@ -576,8 +581,7 @@ def test_subscription_http_roundtrip_long_poll():
     import urllib.request
     full = _panel(4, 148, seed=15)
     old, delta = full[:, :140], full[:, 140:]
-    grown = EDM(full, E_max=3, cache=True)
-    grown.optimal_E()
+    grown = _grown(full, 140, 148, E_max=3, cache=True)
     with EDMServer() as srv:
         httpd = serve_http(srv)
         port = httpd.server_address[1]
