@@ -29,6 +29,8 @@ Memory: a master table holds 2 · N · E_max · L · k_master values (f32 +
 i32). That is the deliberate price of "compute neighbors once, reuse
 everywhere" (kEDM §2.1); sessions on panels too big for it set
 ``cache=False`` or a mesh (sharded plans keep state device-resident).
+The tables derived from it are built one E level at a time, so a
+program reading it holds at most a few level-sized arrays besides it.
 """
 
 from __future__ import annotations
@@ -158,33 +160,61 @@ def panel_master_append(X, dM, iM, *, tau, impl):
                                   tau=tau, impl=impl)[2:]
 
 
+def _compact(valid, tables, fills, *, k):
+    """The first k valid slots of each row, in slot order, by selection.
+
+    ``valid`` (…, K) marks the slots to keep; ``tables`` are (…, K)
+    arrays read at those slots; ``fills`` the value of an output slot
+    that no valid slot reaches. Returns ([(…, k) tables], (…, k) mask).
+
+    A valid slot's output position is the count of valid slots before
+    it (a cumulative sum along the slot axis). Output slot j keeps, by
+    ``jnp.where``, the one slot whose position is j and sets every other
+    slot to the dtype's lowest value, so a max over the slots reads that
+    one value out. Each output value is a copy of one input value (no
+    arithmetic, so inf stays inf), and the slots keep their master
+    order: exactly what a stable argsort of the invalid flags followed
+    by a gather keeps (the form the tests keep as the oracle), without
+    the sort or a gather along the slot axis.
+    """
+    csum = jnp.cumsum(valid, axis=-1, dtype=jnp.int32)
+    pos = jnp.where(valid, csum - 1, k)  # k: no output slot
+    slot = jnp.arange(k, dtype=jnp.int32)
+    hit = pos[..., None, :] == slot[:, None]  # (…, k, K): one slot or none
+    ok = slot < csum[..., -1:]
+    outs = []
+    for t, f in zip(tables, fills):
+        low = (-jnp.inf if jnp.issubdtype(t.dtype, jnp.floating)
+               else jnp.iinfo(t.dtype).min)
+        pick = jnp.max(jnp.where(hit, t[..., None, :], low), axis=-1)
+        outs.append(jnp.where(ok, pick, jnp.asarray(f, t.dtype)))
+    return outs, ok
+
+
 def _derive_idx(iE, *, k, max_idx):
-    """First k master indices surviving a ``max_idx`` cap (stable order).
+    """First k master indices surviving a ``max_idx`` cap (master order).
 
     iE: master index level rows, (…, rows, k_master) — one series or a
-    (B, rows, k_master) batch; all ops are row-independent along the
-    last axis, so the batched call equals the per-series calls
-    bit-for-bit. Returns ((…, rows, k) idx with -1 in slots lacking a
-    valid candidate, validity mask) — index-identical to a capped
-    ``topk_select``.
+    (B, rows, k_master) batch; every op is row-independent, so the
+    batched call equals the per-series calls bit-for-bit. Returns
+    ((…, rows, k) idx with -1 in slots lacking a valid candidate,
+    validity mask) — index-identical to a capped ``topk_select``. The
+    cap may be traced (a capacity panel's valid length). ``_compact``
+    keeps the valid slots in master order, as a stable sort of the
+    invalid flags would, so the result is that sort's, bit for bit.
     """
     valid = (iE >= 0) & (iE <= max_idx)
-    order = jnp.argsort(jnp.where(valid, 0, 1).astype(jnp.int32),
-                        axis=-1)[..., :k]  # jnp.argsort is stable
-    ok = jnp.take_along_axis(valid, order, axis=-1)
-    return jnp.where(ok, jnp.take_along_axis(iE, order, axis=-1), -1), ok
+    (i,), ok = _compact(valid, (iE,), (-1,), k=k)
+    return i, ok
 
 
 def _derive(dE, iE, *, k, max_idx):
     """Like ``_derive_idx`` but also carrying the master distances —
-    bit-identical to a capped ``topk_select`` (see module docstring)."""
+    bit-identical to a capped ``topk_select`` (see module docstring);
+    slots lacking a valid candidate read inf."""
     valid = (iE >= 0) & (iE <= max_idx)
-    order = jnp.argsort(jnp.where(valid, 0, 1).astype(jnp.int32),
-                        axis=1)[:, :k]
-    ok = jnp.take_along_axis(valid, order, axis=1)
-    d = jnp.where(ok, jnp.take_along_axis(dE, order, axis=1), jnp.inf)
-    i = jnp.where(ok, jnp.take_along_axis(iE, order, axis=1), -1)
-    return d, i, ok
+    (d, i), _ = _compact(valid, (dE, iE), (jnp.inf, -1), k=k)
+    return d, i
 
 
 def _gathered_dists(x, idx, ok, *, E, tau):
@@ -219,24 +249,33 @@ def rho_curves_from_master(X, dM, iM, *, E_max, tau, Tp, impl):
     multi-E accumulator, so this is bit-identical to
     ``core.simplex.rho_curve``) and derives each level's Tp-capped
     table post hoc instead of re-running the engine.
+
+    A static loop over the E levels, each over all N series at once:
+    level E derives its (N, rows, E + 1) tables from the master level
+    (N, rows, k_master) by ``_derive``, then runs the weights and each
+    series' self-lookup as a ``lax.map`` over the series, the same
+    per-series ``ops.lookup_rho`` as the legacy sweep (a ``jax.vmap`` of
+    the kernel would turn its flat SMEM tables into 2-D blocks, which
+    Mosaic refuses). The program's intermediates are a few level-sized
+    arrays, bounded by a small multiple of one master level (1/E_max of
+    the master), never of the whole master.
     """
     L = X.shape[-1]
+    rhos = []
+    for E in range(1, E_max + 1):
+        rows = pred_rows(L, E, tau, Tp)
+        mx = num_embedded(L, E, tau) - 1 - Tp
+        off = embed_offset(E, tau, Tp)
+        dk, ik = _derive(dM[:, E - 1, :rows], iM[:, E - 1, :rows],
+                         k=E + 1, max_idx=mx)
 
-    def one(args):
-        x, d, i = args
-        rhos = []
-        for E in range(1, E_max + 1):
-            rows = pred_rows(L, E, tau, Tp)
-            mx = num_embedded(L, E, tau) - 1 - Tp
-            off = embed_offset(E, tau, Tp)
-            dk, ik, _ = _derive(d[E - 1, :rows], i[E - 1, :rows],
-                                k=E + 1, max_idx=mx)
-            w = ops.make_weights(dk)
-            rhos.append(
-                ops.lookup_rho(x[None, :], ik, w, offset=off, impl=impl)[0])
-        return jnp.stack(rhos)
+        def self_rho(args, off=off):
+            x, d, i = args
+            return ops.lookup_rho(x[None, :], i, ops.make_weights(d),
+                                  offset=off, impl=impl)[0]
 
-    return jax.lax.map(one, (X, dM, iM))
+        rhos.append(jax.lax.map(self_rho, (X, dk, ik)))
+    return jnp.stack(rhos, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("E", "tau", "Tp", "k", "impl"))
@@ -391,10 +430,13 @@ def make_master_group_launch(X, iM_E, targets, *, E, tau, Tp, k, impl):
 def master_group_batch_bytes(Lp: int, k_master: int) -> int:
     """Per-series in-flight bytes of one master-derived launch.
 
-    ~4 live (B, Lp, k_master)-sized buffers per launch (validity, sort
-    keys/order, gathered dists) — the footprint ``auto_batch_libs``
-    should size against for this engine (NOT the direct engine's
-    (B, Lp, Lp) distance stack, which derivation never holds).
+    ~4 live (B, Lp, k_master)-sized 4-byte buffers per launch (the
+    derivation's slot positions and validity, the derived indices, the
+    recomputed distances) — the footprint ``auto_batch_libs`` should
+    size against for this engine (NOT the direct engine's (B, Lp, Lp)
+    distance stack, which derivation never holds). The compaction that
+    replaced a sort holds positions where the sort held its keys and
+    order, so the count is unchanged.
     """
     return 16 * Lp * int(k_master)
 

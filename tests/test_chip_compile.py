@@ -164,6 +164,24 @@ def test_ccm_batch_step_compiles(one_chip):
         E=E, tau=1, Tp=0, k=E + 1, impl="pallas"))
 
 
+def _lower_rho_curves(one_chip, N=4, L=1600):
+    return edm_plan.rho_curves_from_master.lower(
+        _spec(one_chip, (N, L)), _spec(one_chip, (N, E_MAX, L, K_MASTER)),
+        _spec(one_chip, (N, E_MAX, L, K_MASTER), jnp.int32), E_max=E_MAX,
+        tau=1, Tp=1, impl="pallas")
+
+
+def test_rho_curves_from_master_compiles(one_chip):
+    """The optimal-E curves of a Fish1_Normo panel from its master: one
+    ``lookup_rho`` per E level, and intermediates of level size — at
+    most the master's own bytes, never a multiple of them."""
+    N, L = 154, 1600
+    compiled = _compile_ok(_lower_rho_curves(one_chip, N, L),
+                           kernels=E_MAX)
+    master_bytes = 2 * N * E_MAX * L * K_MASTER * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= master_bytes
+
+
 @pytest.mark.parametrize("L", LENGTHS)
 def test_ccm_group_step_compiles(one_chip, L):
     """The one-shot ``xmap`` engine launch: batched kNN + per-series ρ."""
@@ -195,12 +213,13 @@ def _lower_knn_append(one_chip):
     (_lower_panel_master, "knn_multi_e"),
     (_lower_knn_append, "knn_append"),
     (_lower_knn_append, "knn_append_fold"),
+    (_lower_rho_curves, "lookup_rho"),
 ])
 def test_kernel_programs_are_named(one_chip, lower, kernel):
-    """The program around each kNN kernel carries the kernel's name, which
+    """The program around each kernel carries the kernel's name, which
     the HLO instruction takes (``%knn_batch.N``, ``%knn_multi_e.N``,
-    ``%knn_append.N``, ``%knn_append_fold.N``), so a trace tells the
-    kernels apart by name alone."""
+    ``%knn_append.N``, ``%knn_append_fold.N``, ``%lookup_rho.N``), so a
+    trace tells the kernels apart by name alone."""
     funcs = set(re.findall(r"func\.func (?:private |public )?@([\w.]+)",
                            lower(one_chip).as_text()))
     assert kernel in funcs
